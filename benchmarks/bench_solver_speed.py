@@ -50,6 +50,7 @@ from repro.core.solver.interlayer import (                      # noqa: E402
     segment_pool)
 from repro.core.solver.intralayer import Constraints            # noqa: E402
 from repro.hw.presets import eyeriss_multinode                  # noqa: E402
+from repro.kernels.backend import configure_compile_cache       # noqa: E402
 from repro.workloads.layers import conv                         # noqa: E402
 from repro.workloads.nets import get_net, transformer           # noqa: E402
 
@@ -898,6 +899,7 @@ def main(argv=None) -> int:
                     "exported trace shows the node kill, backup dispatch, "
                     "repartition and injected faults as events")
     args = ap.parse_args(argv)
+    configure_compile_cache()
     only = args.calibrate_only or args.network_only or args.service_only \
         or args.chaos_only or args.multinode_only or args.obs_only
     if only and (args.min_speedup is not None

@@ -14,10 +14,13 @@ execution-backend tier (``lower.exec`` / ``lower.netexec`` / ``lower.fuse``)
     =============  ========================================================
     ``interpret``  per-layer ``pl.pallas_call(interpret=True)`` — the
                    bit-accuracy **oracle**; runs everywhere, slowly.
-    ``pallas``     per-layer compiled ``pl.pallas_call`` — TPU silicon.
+    ``pallas``     per-layer compiled ``pl.pallas_call`` for the TPU;
+                   plans whose blocks break the TPU tiling are refused
+                   before compiling (``exec._check_tpu_tiling``).
     ``compiled``   fused XLA segments (``lower.fuse``): every kernel of a
                    chain segment traced into **one** jitted executable —
-                   the default measured path.
+                   the default measured path, and the one that runs on
+                   the chip (``chip_smoke.py``).
     =============  ========================================================
 
 ``resolve_backend`` also accepts the legacy ``interpret`` bool so existing
@@ -26,7 +29,9 @@ call sites keep their meaning: ``interpret=True`` -> ``"interpret"``,
 """
 from __future__ import annotations
 
-from typing import Optional
+import os
+from pathlib import Path
+from typing import Dict, Optional
 
 import jax
 
@@ -41,11 +46,35 @@ ORACLE_BACKEND = "interpret"
 
 
 def on_tpu() -> bool:
-    """True when jax's default backend is a TPU."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    """True when jax's default backend is a TPU.  A backend that fails to
+    initialize raises: it is not silently taken for "not a TPU"."""
+    return jax.default_backend() == "tpu"
+
+
+def device_info() -> Dict:
+    """The device JAX runs on, named the way every measurement record
+    names it, so a CPU timing is never read as a chip timing."""
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+
+
+#: JAX's persistent compilation cache when ``JAX_COMPILATION_CACHE_DIR``
+#: is unset: a fixed directory in the checkout (the path is part of the
+#: cache key, so it never moves; ``.gitignore`` lists it)
+DEFAULT_COMPILE_CACHE = str(Path(__file__).resolve().parents[3]
+                            / ".jax_cache")
+
+
+def configure_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at
+    ``$JAX_COMPILATION_CACHE_DIR`` when it is set, else at
+    ``DEFAULT_COMPILE_CACHE``, and return the directory.  Entry points
+    call this once; importing the package configures nothing."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") \
+        or DEFAULT_COMPILE_CACHE
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def default_impl() -> str:
@@ -83,5 +112,6 @@ def backend_interprets(backend: str) -> bool:
 
 
 __all__ = ["BACKENDS", "DEFAULT_BACKEND", "ORACLE_BACKEND", "on_tpu",
+           "device_info", "DEFAULT_COMPILE_CACHE", "configure_compile_cache",
            "default_impl", "resolve_impl", "resolve_backend",
            "backend_interprets"]

@@ -36,7 +36,8 @@ from ..core.solver.intralayer import Constraints, solve_intra_layer
 from ..hw.template import HWTemplate
 from ..hw.presets import eyeriss_multinode
 from ..workloads.layers import LayerSpec, attention, conv, fc
-from .exec import make_inputs, plan_runner, reference_output, rel_error
+from .exec import (ORACLE_TOL, make_inputs, plan_runner, reference_output,
+                   rel_error)
 from .plan import lower_scheme
 
 
@@ -200,7 +201,7 @@ def run_calibration(hw: Optional[HWTemplate] = None, quick: bool = True,
             if verify:
                 err = rel_error(out, reference_output(plan, inputs))
                 entry["rel_err"] = err
-                if err >= 1e-3:
+                if err >= ORACLE_TOL:
                     skipped.append({"layer": layer.name, "variant": vi,
                                     "reason": f"numerics {err:.2e}"})
                     continue
@@ -256,7 +257,7 @@ def default_network_sweep(quick: bool = True):
 def run_network_calibration(hw: Optional[HWTemplate] = None,
                             quick: bool = True, nets=None,
                             interpret: bool = True, iters: int = 2,
-                            seed: int = 0, tol: float = 1e-3,
+                            seed: int = 0, tol: float = ORACLE_TOL,
                             backend: Optional[str] = None) -> Dict:
     """End-to-end network calibration: each net is solved, lowered to a
     ``NetworkPlan``, verified against the whole-graph reference pass, and

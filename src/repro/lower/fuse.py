@@ -67,8 +67,16 @@ _m_compile = metrics.histogram(
 # compiled per-layer kernels (pure jnp, traced into the segment executable)
 # ---------------------------------------------------------------------------
 
+#: matmul precision of every compiled-tier product.  f32 operands at
+#: HIGHEST, like the ``kernels/ref.py`` oracles: on the TPU the default
+#: precision is a single bf16 pass, which would make the chip compute
+#: something other than what the CPU tests check (``exec.ORACLE_TOL``).
+MATMUL_PRECISION = jax.lax.Precision.HIGHEST
+
+
 def _fc(plan: KernelPlan, x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
-    return jnp.dot(x, w, preferred_element_type=jnp.float32)
+    return jnp.dot(x, w, precision=MATMUL_PRECISION,
+                   preferred_element_type=jnp.float32)
 
 
 def _conv(plan: KernelPlan, x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
@@ -86,6 +94,7 @@ def _conv(plan: KernelPlan, x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
                  s + (YO - 1) * stride + 1),
                 (1, 1, stride, stride))      # [N, C, XO, YO]
             acc += jnp.einsum("ncxy,kc->nkxy", patch, w[:, :, r, s],
+                              precision=MATMUL_PRECISION,
                               preferred_element_type=jnp.float32)
     return acc
 
@@ -118,10 +127,10 @@ def _eltwise(plan: KernelPlan, xs) -> jnp.ndarray:
 def _attention(plan: KernelPlan, q: jnp.ndarray, k: jnp.ndarray,
                v: jnp.ndarray) -> jnp.ndarray:
     scale = plan.layer.dim("K") ** -0.5
-    s = jnp.einsum("nqd,nkd->nqk", q, k,
+    s = jnp.einsum("nqd,nkd->nqk", q, k, precision=MATMUL_PRECISION,
                    preferred_element_type=jnp.float32) * scale
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("nqk,nkd->nqd", p, v,
+    return jnp.einsum("nqk,nkd->nqd", p, v, precision=MATMUL_PRECISION,
                       preferred_element_type=jnp.float32)
 
 

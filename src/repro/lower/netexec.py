@@ -43,8 +43,9 @@ from ..kernels import ref
 from ..kernels.backend import backend_interprets, resolve_backend
 from ..obs import metrics, trace, watch
 from ..workloads.layers import LayerSpec
-from .exec import (_check_compiled_revisit_order, _run_conv, _run_eltwise,
-                   _run_fc, _run_pool, input_extent, rel_error)
+from .exec import (ORACLE_TOL, _check_compiled_pallas, _run_conv,
+                   _run_eltwise, _run_fc, _run_pool, input_extent,
+                   rel_error)
 from .netplan import NetworkPlan
 
 
@@ -257,10 +258,10 @@ def network_runner(nplan: NetworkPlan, inputs: Dict,
 
     _check_executable(nplan)
     if backend == "pallas":
-        # compiled Pallas cannot accumulate across non-consecutive output-
-        # block revisits: apply the layer tier's guard to every plan
+        # apply the layer tier's compiled-Pallas guards (revisit order,
+        # TPU tiling) to every plan before anything compiles
         for name in nplan.order:
-            _check_compiled_revisit_order(nplan.plans[name])
+            _check_compiled_pallas(nplan.plans[name])
     steps = []
     for name in nplan.order:
         fn, srcs = _layer_fn(nplan, name, inputs,
@@ -345,7 +346,8 @@ class NetworkVerification:
 
 
 def compare_network(nplan: NetworkPlan, ex: NetworkExecution,
-                    inputs: Dict, tol: float = 1e-3) -> NetworkVerification:
+                    inputs: Dict,
+                    tol: float = ORACLE_TOL) -> NetworkVerification:
     """Compare **every** layer output of an execution against the
     whole-graph reference pass (per-layer max relative error) — the one
     comparison rule shared by ``verify_network``, the calibration sweep
@@ -359,7 +361,7 @@ def compare_network(nplan: NetworkPlan, ex: NetworkExecution,
 
 
 def verify_network(nplan: NetworkPlan, interpret: bool = True,
-                   seed: int = 0, tol: float = 1e-3, jit: bool = True,
+                   seed: int = 0, tol: float = ORACLE_TOL, jit: bool = True,
                    backend: Optional[str] = None) -> NetworkVerification:
     """Execute the plan and compare against the whole-graph reference
     (one-shot convenience over ``compare_network``).  The default backend

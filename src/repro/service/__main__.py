@@ -13,12 +13,15 @@
 it twice demonstrates the cached path.  ``warm`` forces a warm-start
 solve seeded from the nearest family record (same net, different batch).
 ``autotune`` lowers + executes the top-k candidates and promotes the
-measured winner.  ``stats`` includes the resilience counters (corrupt /
-quarantined / io_errors / rebuilds); ``stats --json`` adds the full
-``repro.obs`` metrics-registry snapshot and ``--prom`` emits Prometheus
-text exposition.  ``repair`` rebuilds the store
-index from the records dir, quarantining corrupt records.  The store dir
-defaults to ``$REPRO_STORE_DIR`` or ``.repro_store``.
+measured winner; it exits non-zero when any candidate was skipped, and
+its report names the device the measurements ran on.  ``stats``
+includes the resilience counters (corrupt / quarantined / io_errors /
+rebuilds); ``stats --json`` adds the full ``repro.obs`` metrics-registry
+snapshot and ``--prom`` emits Prometheus text exposition.  ``repair``
+rebuilds the store index from the records dir, quarantining corrupt
+records.  The store dir defaults to ``$REPRO_STORE_DIR`` or
+``.repro_store``.  JAX's persistent compilation cache goes to
+``$JAX_COMPILATION_CACHE_DIR``, else to ``.jax_cache`` in the checkout.
 """
 from __future__ import annotations
 
@@ -30,6 +33,7 @@ from typing import List, Optional
 
 from ..core.solver.kapla import solve
 from ..hw.presets import eyeriss_multinode
+from ..kernels.backend import configure_compile_cache
 from ..workloads.nets import NETS, get_net
 from .autotune import autotune_network
 from .client import LocalClient, SolveRequest, warm_context
@@ -168,6 +172,7 @@ def cmd_repair(args) -> int:
 
 
 def cmd_autotune(args) -> int:
+    configure_compile_cache()            # the one verb that compiles
     store = ScheduleStore(args.store_dir)
     req = _request(args)
     report = autotune_network(req.graph, req.hw, store=store, k=args.k,
@@ -175,7 +180,11 @@ def cmd_autotune(args) -> int:
                               candidate_timeout_s=args.candidate_timeout,
                               **req.opts)
     print(json.dumps(report, indent=1))
-    return 0 if report.get("n_executed") else 1
+    for s in report["skipped"]:
+        print(f"autotune: candidate {s['rank']} skipped: {s['reason']}",
+              file=sys.stderr)
+    ok = report["n_executed"] and not report["skipped"]
+    return 0 if ok else 1
 
 
 def main(argv: Optional[List[str]] = None) -> int:

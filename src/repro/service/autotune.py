@@ -90,7 +90,7 @@ def autotune_network(graph: LayerGraph, hw: HWTemplate,
                      iters: int = 2, interpret: Optional[bool] = None,
                      seed: int = 0,
                      max_workers: Optional[int] = None,
-                     tol: float = 1e-3,
+                     tol: Optional[float] = None,
                      candidate_timeout_s: Optional[float] = None,
                      backend: Optional[str] = None,
                      explain: bool = False,
@@ -106,11 +106,16 @@ def autotune_network(graph: LayerGraph, hw: HWTemplate,
     default): top-k candidates of the same graph share a plan-signature
     keyed executable cache, so re-measuring a candidate never re-traces.
     Pass ``backend="interpret"`` (or legacy ``interpret=True``) to rank
-    on the bit-accuracy oracle instead."""
-    from ..kernels.backend import resolve_backend
+    on the bit-accuracy oracle instead.  ``tol`` defaults to the stated
+    oracle tolerance (``lower.exec.ORACLE_TOL``).  The report names the
+    backend and the device (``platform``, ``kind``, ``count``) the
+    measurements ran on."""
+    from ..kernels.backend import device_info, resolve_backend
     from ..lower.calibrate import spearman
+    from ..lower.exec import ORACLE_TOL
 
     backend = resolve_backend(backend, interpret)
+    tol = ORACLE_TOL if tol is None else tol
 
     opts = solver_options(**options)
     t0 = time.perf_counter()
@@ -153,6 +158,8 @@ def autotune_network(graph: LayerGraph, hw: HWTemplate,
     report: Dict = {
         "net": graph.name,
         "hw": hw.name,
+        "backend": backend,
+        "device": device_info(),
         "options": opts,
         "k_requested": k,
         "n_candidates": len(cands),

@@ -104,6 +104,32 @@ def test_network_runner_compiled_backend():
                            backend="compiled") > 0
 
 
+def test_oracle_tolerance_fails_a_dropped_filter_tap(monkeypatch):
+    """``ORACLE_TOL`` is tight enough to catch a wrong kernel: the
+    compiled conv with one filter tap dropped fails the comparison that
+    the right conv passes."""
+    from repro.lower import fuse
+    from repro.lower.exec import ORACLE_TOL
+    from repro.lower.netexec import NetworkExecution, compare_network
+    nplan = _plan(get_net("alexnet", batch=1))
+    inputs = make_network_inputs(nplan, seed=0)
+
+    def verify():
+        out = FusedNetwork(nplan)(inputs, keep="all")   # fresh trace
+        ex = NetworkExecution(outputs=out, forwarded=(), roundtrips=(),
+                              seconds=0.0, backend="compiled")
+        return compare_network(nplan, ex, inputs)
+
+    right = verify()
+    assert right.ok and right.max_rel_err < ORACLE_TOL
+    conv = fuse._conv
+    monkeypatch.setattr(fuse, "_conv", lambda plan, x, w: conv(
+        plan, x, w.at[:, :, 0, 0].set(0.0)))
+    wrong = verify()
+    assert not wrong.ok and wrong.max_rel_err > 10 * ORACLE_TOL
+    assert nplan.plans[wrong.worst_layer].kind == "conv"
+
+
 # ---------------------------------------------------------------------------
 # the executable cache: hit on re-execution, zero retrace
 # ---------------------------------------------------------------------------
