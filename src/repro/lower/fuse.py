@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import hashlib
 import json
+import re
 import threading
 import time
 from collections import OrderedDict
@@ -60,7 +61,8 @@ _m_size = metrics.gauge("fused_cache_size",
                         "fused executables resident in the process cache")
 _m_compile = metrics.histogram(
     "fused_compile_seconds",
-    "wall clock per fused-executable trace+compile")
+    "wall clock of a fused-executable call that traced: trace, compile "
+    "(or persistent-cache load) and one execution")
 
 
 # ---------------------------------------------------------------------------
@@ -189,9 +191,9 @@ def plan_signature(nplan: NetworkPlan) -> str:
 
 def input_specs(nplan: NetworkPlan) -> Dict[str, jax.ShapeDtypeStruct]:
     """Abstract shapes of the plan's external feed (mirrors
-    ``make_network_inputs``) — what the fused executable is traced for."""
-    return {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
-            for k, v in make_network_inputs(nplan, seed=0).items()}
+    ``make_network_inputs``, with no array made) — what the fused
+    executable is traced for."""
+    return jax.eval_shape(lambda: make_network_inputs(nplan, seed=0))
 
 
 # ---------------------------------------------------------------------------
@@ -204,7 +206,9 @@ def _layer_out(nplan: NetworkPlan, name: str, vals: Dict,
     ``vals`` (in-graph), falling back to the external ``feed`` (the
     ``.I`` inputs — and, at segment granularity, boundary tensors from
     earlier segments), the canonical adapter inline — the traced mirror
-    of ``netexec._layer_fn``."""
+    of ``netexec._layer_fn``.  Everything it traces, adapter included,
+    sits under ``jax.named_scope(name)``: the layer's name reaches each
+    compiled instruction's ``op_name`` (``FusedNetwork.op_layers``)."""
     plan = nplan.plans[name]
     layer = plan.layer
     srcs = [s for s in layer.src if s in nplan.plans]
@@ -213,20 +217,51 @@ def _layer_out(nplan: NetworkPlan, name: str, vals: Dict,
         return vals[s] if s in vals else feed[s]
 
     shape = required_input_shape(layer)
-    if plan.kind == "eltwise":
-        ops = _eltwise_operands(
-            [src_val(s) for s in srcs] if srcs else [feed[f"{name}.I"]],
-            layer)
-        return _eltwise(plan, ops)
-    x = adapt_tensor(src_val(srcs[0]) if srcs else feed[f"{name}.I"], shape)
-    if plan.kind == "fc":
-        return _fc(plan, x, feed[f"{name}.W"])
-    if plan.kind == "conv":
-        return _conv(plan, x, feed[f"{name}.W"])
-    if plan.kind == "pool":
-        return _pool(plan, x)
+    with jax.named_scope(name):
+        if plan.kind == "eltwise":
+            ops = _eltwise_operands(
+                [src_val(s) for s in srcs] if srcs else [feed[f"{name}.I"]],
+                layer)
+            return _eltwise(plan, ops)
+        x = adapt_tensor(src_val(srcs[0]) if srcs else feed[f"{name}.I"],
+                         shape)
+        if plan.kind == "fc":
+            return _fc(plan, x, feed[f"{name}.W"])
+        if plan.kind == "conv":
+            return _conv(plan, x, feed[f"{name}.W"])
+        if plan.kind == "pool":
+            return _pool(plan, x)
     raise ValueError(f"cannot execute layer {name!r}: kind "
                      f"{plan.kind!r} has no network-exec input feed")
+
+
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.-]+)\s*=")
+_HLO_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+
+
+def hlo_op_layers(hlo_text: str, layers) -> Dict[str, str]:
+    """``{instruction: layer}`` over the ENTRY computation of an HLO
+    module's text: each instruction whose ``op_name`` metadata carries a
+    ``jax.named_scope`` of one of ``layers`` (``jit(fn)/pool1/max`` ->
+    ``pool1``).  Instructions outside every layer scope (parameters and
+    their copies, ``copy-start``/``copy-done``) are left out."""
+    names = set(layers)
+    out: Dict[str, str] = {}
+    in_entry = False
+    for line in hlo_text.splitlines():
+        if not in_entry:
+            in_entry = line.startswith("ENTRY ")
+            continue
+        if line.startswith("}"):
+            break
+        instr, op = _HLO_INSTR.match(line), _HLO_OP_NAME.search(line)
+        if instr is None or op is None:
+            continue
+        layer = next((p for p in op.group(1).split("/")[1:] if p in names),
+                     None)
+        if layer is not None:
+            out[instr.group(1)] = layer
+    return out
 
 
 def _segment_io(nplan: NetworkPlan, seg) -> Tuple[Tuple[str, ...],
@@ -262,6 +297,11 @@ class FusedNetwork:
 
     ``traces`` counts actual jax retraces (a Python side effect at trace
     time): the zero-retrace guarantee the executable cache is tested on.
+
+    Each call is traced by ``obs.trace``: ``fuse.feed`` splits the feed,
+    ``fuse.compile`` covers the first call of a jitted variant (trace,
+    compile or cache load, one run) and ``fuse.dispatch`` every later
+    call, until the jitted call returns.
     """
 
     def __init__(self, nplan: NetworkPlan):
@@ -270,6 +310,7 @@ class FusedNetwork:
         self.signature = plan_signature(nplan)
         self.traces = 0
         self._fns: Dict[Tuple, Callable] = {}
+        self._called: set = set()            # variants run at least once
         self._lock = threading.Lock()
         self.segment_io = [_segment_io(nplan, seg)
                            for seg in nplan.segments]
@@ -319,19 +360,38 @@ class FusedNetwork:
                 self._fns[key] = fn
         return fn
 
-    def _timed(self, fn: Callable, *args):
-        """Invoke a jitted variant; when the call traced (first execution
-        for its shapes), record the compile span + histogram."""
+    def _timed(self, key: Tuple, *args):
+        """Invoke a jitted variant under its host span (``fuse.compile``
+        on the variant's first call, ``fuse.dispatch`` after); when the
+        call traced, record its wall clock in ``fused_compile_seconds``."""
+        fn = self._fn(key)
+        with self._lock:
+            first = key not in self._called
+            self._called.add(key)
+        span = (trace.span("fuse.compile", net=self.nplan.graph_name,
+                           signature=self.signature[:12])
+                if first else trace.span("fuse.dispatch"))
         before = self.traces
         t0 = time.perf_counter()
-        out = fn(*args)
+        with span:
+            out = fn(*args)
         if self.traces > before:
-            dt = time.perf_counter() - t0
-            _m_compile.observe(dt)
-            trace.instant("fuse.compile", net=self.nplan.graph_name,
-                          signature=self.signature[:12],
-                          seconds=round(dt, 6))
+            _m_compile.observe(time.perf_counter() - t0)
         return out
+
+    def op_layers(self, keep: str = "boundary") -> Dict[str, str]:
+        """``{HLO instruction: layer}`` of the whole-net executable (the
+        ``keep`` variant, not donating), read from its compiled text: the
+        join key between a device trace's ``XLA Ops`` events, which are
+        named by instruction, and the plan's layers.  Lowers and compiles
+        the variant (a cache load when it was compiled before); a
+        set-up-time call, never on the serving path."""
+        specs = input_specs(self.nplan)
+        acts = {k: v for k, v in specs.items() if not k.endswith(".W")}
+        weights = {k: v for k, v in specs.items() if k.endswith(".W")}
+        text = self._fn(("net", keep, False)).lower(
+            acts, weights).compile().as_text()
+        return hlo_op_layers(text, self.nplan.order)
 
     # -- execution ----------------------------------------------------------
 
@@ -345,15 +405,17 @@ class FusedNetwork:
         donated inputs must not be reused by the caller."""
         if keep not in ("all", "boundary"):
             raise ValueError(f"keep must be 'all'|'boundary', got {keep!r}")
-        acts = {k: v for k, v in inputs.items() if not k.endswith(".W")}
-        weights = {k: v for k, v in inputs.items() if k.endswith(".W")}
-        return self._timed(self._fn(("net", keep, donate)), acts, weights)
+        with trace.span("fuse.feed"):
+            acts = {k: v for k, v in inputs.items()
+                    if not k.endswith(".W")}
+            weights = {k: v for k, v in inputs.items() if k.endswith(".W")}
+        return self._timed(("net", keep, donate), acts, weights)
 
     def run_segment(self, index: int, state: Dict) -> Dict:
         """Run one fused segment executable over a boundary-state dict
         (must hold the segment's ``consumes`` names) — the mesh executor's
         per-task unit."""
-        return self._timed(self._fn(("seg", index)), state)
+        return self._timed(("seg", index), state)
 
 
 # ---------------------------------------------------------------------------
@@ -412,5 +474,5 @@ def clear_cache() -> None:
 
 
 __all__ = ["FusedNetwork", "fused_runner", "plan_signature", "input_specs",
-           "compiled_plan_fn", "cache_stats", "clear_cache",
-           "resolve_backend"]
+           "compiled_plan_fn", "hlo_op_layers", "cache_stats",
+           "clear_cache", "resolve_backend"]

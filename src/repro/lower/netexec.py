@@ -247,13 +247,15 @@ def network_runner(nplan: NetworkPlan, inputs: Dict,
         boundary = tuple(n for n in nplan.order if n not in fwd)
 
         def run_fused() -> NetworkExecution:
-            t0 = time.perf_counter()
-            outputs = fused(inputs, keep=keep)
-            for v in outputs.values():
-                jax.block_until_ready(v)
-            return NetworkExecution(
-                outputs=outputs, forwarded=fwd, roundtrips=boundary,
-                seconds=time.perf_counter() - t0, backend=backend)
+            with trace.span("netexec.run"):
+                t0 = time.perf_counter()
+                outputs = fused(inputs, keep=keep)
+                with trace.span("netexec.wait"):
+                    for v in outputs.values():
+                        jax.block_until_ready(v)
+                return NetworkExecution(
+                    outputs=outputs, forwarded=fwd, roundtrips=boundary,
+                    seconds=time.perf_counter() - t0, backend=backend)
         return run_fused
 
     _check_executable(nplan)

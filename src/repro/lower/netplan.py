@@ -32,6 +32,7 @@ import dataclasses
 from typing import Dict, List, Optional, Tuple
 
 from ..hw.template import HWTemplate
+from ..obs import trace
 from ..workloads.layers import LayerGraph
 from ..core.solver.interlayer import _consumer_map
 from .plan import KernelPlan, lower_scheme
@@ -153,8 +154,14 @@ def lower_network(schedule, graph: LayerGraph, hw: HWTemplate,
     Layers missing a scheme (partial schedules) and unsupported kinds come
     back as invalid kernel plans with reasons — the plan reports them via
     ``invalid_layers()`` instead of raising, so callers can see exactly
-    what is and is not executable.
+    what is and is not executable.  Traced as ``lower.network``.
     """
+    with trace.span("lower.network", graph=graph.name):
+        return _lower_network(schedule, graph, hw, repair)
+
+
+def _lower_network(schedule, graph: LayerGraph, hw: HWTemplate,
+                   repair: bool) -> NetworkPlan:
     consumers = _consumer_map(graph)
     segs = _segments(schedule, graph)
     seg_of: Dict[str, int] = {}

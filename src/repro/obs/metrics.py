@@ -29,7 +29,8 @@ histograms — e.g. ``store_events_total{event="hits"}``,
 different series per execution backend, interpreter seconds and fused
 compiled-XLA seconds being different units).  The compiled tier adds
 ``fused_cache_events_total{event}`` / ``fused_cache_size`` /
-``fused_compile_seconds`` (``repro.lower.fuse``).
+``fused_compile_seconds`` (``repro.lower.fuse``: the wall clock of each
+call that traced, which includes one execution).
 """
 from __future__ import annotations
 

@@ -18,6 +18,14 @@ Design constraints, in order:
 3. **Zero dependencies.**  stdlib only; the export target is the Chrome
    trace-event JSON format (``{"traceEvents": [...]}``), which Perfetto
    (https://ui.perfetto.dev) and ``chrome://tracing`` both load.
+4. **One clock with the device, on request.**  ``Tracer(profiler=True)``
+   also enters a ``jax.profiler.TraceAnnotation`` for every span, so the
+   span lands on the host plane of an active ``jax.profiler`` session, on
+   the clock its device events use.  ``jax`` is imported only then.
+
+While a tracer is installed, every Python garbage collection is a
+``host.gc`` span (``gc.callbacks``), so a host stall can be told apart
+from the program's own work.
 
 Usage::
 
@@ -35,6 +43,7 @@ JSON-safe scalars and land in the event's ``args``.
 """
 from __future__ import annotations
 
+import gc
 import json
 import os
 import threading
@@ -65,12 +74,13 @@ NOOP_SPAN = _NoopSpan()
 class Span:
     """One live timed region; records itself into the tracer on exit."""
 
-    __slots__ = ("_tracer", "name", "args", "t0")
+    __slots__ = ("_tracer", "name", "args", "t0", "_mirror")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict):
         self._tracer = tracer
         self.name = name
         self.args = args
+        self._mirror = None
 
     def set(self, **attrs) -> None:
         """Attach attributes decided after the span opened (e.g. the
@@ -78,14 +88,20 @@ class Span:
         self.args.update(attrs)
 
     def __enter__(self) -> "Span":
+        annotation = self._tracer._annotation
+        if annotation is not None:
+            self._mirror = annotation(self.name)
+            self._mirror.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        t1 = time.perf_counter()
+        if self._mirror is not None:
+            self._mirror.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self.args.setdefault("error", exc_type.__name__)
-        self._tracer._complete(self.name, self.t0, time.perf_counter(),
-                               self.args)
+        self._tracer._complete(self.name, self.t0, t1, self.args)
         return False
 
 
@@ -95,15 +111,34 @@ class Tracer:
     Thread-safe; events carry (name, phase, t0, dur, thread ident,
     thread name, args) with times relative to the tracer's epoch.
     ``events`` rows are dicts — tests assert on them directly, the
-    exporter maps them to trace-event JSON.
+    exporter maps them to trace-event JSON.  ``profiler=True`` mirrors
+    every span into the JAX profiler (``jax.profiler.TraceAnnotation``).
     """
 
-    def __init__(self):
+    def __init__(self, profiler: bool = False):
         self.epoch = time.perf_counter()
-        self._lock = threading.Lock()
+        self._annotation = None
+        if profiler:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
+        # reentrant: a host.gc span may close inside another append
+        self._lock = threading.RLock()
         self.events: List[Dict] = []
         self.dropped = 0
         self.max_events = 1_000_000     # runaway-trace backstop
+        self._gc_span: Optional[Span] = None
+
+    def _on_gc(self, phase: str, info: Dict) -> None:
+        """``gc.callbacks`` hook while installed: one ``host.gc`` span per
+        collection (collections do not nest)."""
+        if phase == "start":
+            self._gc_span = self.span("host.gc",
+                                      generation=info["generation"])
+            self._gc_span.__enter__()
+        elif self._gc_span is not None:
+            sp, self._gc_span = self._gc_span, None
+            sp.set(collected=info["collected"])
+            sp.__exit__(None, None, None)
 
     # -- recording -----------------------------------------------------------
     def _append(self, ev: Dict) -> None:
@@ -189,9 +224,12 @@ def current() -> Optional[Tracer]:
 
 
 def enable(tracer: Optional[Tracer] = None) -> Tracer:
-    """Install (and return) the process-wide tracer."""
+    """Install (and return) the process-wide tracer; while installed it
+    records a ``host.gc`` span per garbage collection."""
     global _tracer
+    disable()
     _tracer = tracer if tracer is not None else Tracer()
+    gc.callbacks.append(_tracer._on_gc)
     return _tracer
 
 
@@ -200,6 +238,8 @@ def disable() -> Optional[Tracer]:
     global _tracer
     t = _tracer
     _tracer = None
+    if t is not None and t._on_gc in gc.callbacks:
+        gc.callbacks.remove(t._on_gc)
     return t
 
 

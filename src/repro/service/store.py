@@ -55,7 +55,7 @@ from typing import Dict, List, Mapping, Optional, Sequence
 
 from ..core.solver.kapla import NetworkSchedule
 from ..hw.template import HWTemplate
-from ..obs import metrics
+from ..obs import metrics, trace
 from ..runtime import inject
 from ..workloads.layers import LayerGraph
 from .signature import family_signature, schedule_signature, solver_options
@@ -369,11 +369,13 @@ class ScheduleStore:
             ) -> Optional[NetworkSchedule]:
         """The stored schedule for ``sig``, re-bound to ``graph`` when
         given (positionally if the graph's layer names differ from the
-        stored ones — signatures are name-insensitive)."""
-        rec = self.get_record(sig)
-        if rec is None:
-            return None
-        return self._bind(rec, graph)
+        stored ones — signatures are name-insensitive).  Traced as
+        ``store.get``."""
+        with trace.span("store.get", sig=sig[:12]):
+            rec = self.get_record(sig)
+            if rec is None:
+                return None
+            return self._bind(rec, graph)
 
     def _bind(self, rec: StoreRecord, graph: Optional[LayerGraph]
               ) -> NetworkSchedule:
@@ -406,7 +408,15 @@ class ScheduleStore:
         """Insert (or overwrite) the record for one solved schedule;
         returns the written record.  Invalid schedules are refused.
         Raises ``StoreError`` on I/O failure (the record is atomic: it is
-        either fully written or absent)."""
+        either fully written or absent).  Traced as ``store.put``."""
+        with trace.span("store.put"):
+            return self._put(schedule, graph, hw, options, sig, family,
+                             measured)
+
+    def _put(self, schedule: NetworkSchedule, graph: LayerGraph,
+             hw: HWTemplate, options: Optional[Mapping] = None,
+             sig: Optional[str] = None, family: Optional[str] = None,
+             measured: Optional[Dict] = None) -> StoreRecord:
         if not schedule.valid:
             raise ValueError("refusing to store an invalid schedule")
         opts = solver_options(**dict(options or {}))
