@@ -23,15 +23,20 @@ This module is the compiled tier that kills that tax:
     same plan — autotune top-k re-ranking, ``SolveServer`` measured
     re-ranking, mesh task replay — pay tracing/compilation exactly once.
 
-Each layer's compiled kernel computes the same in-block math as its
-Pallas twin in ``exec.py`` (conv/pool keep the R/S window pinned
-in-block as slice + einsum/max loops), so the fused path is an
-independent implementation from the ``kernels/ref.py`` oracles it is
-verified against.  What the compiled tier does *not* replay is the
-solver's DRAM-level grid walk: XLA owns the loop schedule inside a fused
-segment, which is exactly the point — the solver's inter-layer decisions
-(segmentation, forwarding) shape the executable, the intra-layer nest is
-the cost model's concern and stays measurable on the interpret oracle.
+Each layer's compiled kernel computes the layer's math in plain XLA,
+independent of the ``kernels/ref.py`` oracles it is verified against.
+A conv is one ``lax.conv_general_dilated`` at HIGHEST: XLA's windowed
+convolution folds the R/S taps into the contraction and writes the
+output once.  The pool keeps the R/S window as a slice + max loop.  The
+Pallas twins in ``exec.py`` keep the per-tap loop in-block, and the
+interpret tier built from them is the tap-loop implementation the tests
+hold against the oracles.
+
+What the compiled tier does *not* replay is the solver's DRAM-level
+grid walk: XLA owns the loop schedule inside a fused segment, which is
+exactly the point — the solver's inter-layer decisions (segmentation,
+forwarding) shape the executable, the intra-layer nest is the cost
+model's concern and stays measurable on the interpret oracle.
 """
 from __future__ import annotations
 
@@ -82,23 +87,30 @@ def _fc(plan: KernelPlan, x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
 
 
 def _conv(plan: KernelPlan, x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    """XLA's windowed convolution over the adapted input: ``x`` is
+    exactly ``input_extent(layer)`` = ``(X-1)*stride + R`` wide, so a
+    VALID window walk yields ``[N, K, X, Y]`` and the R x S taps fold
+    into the contraction instead of a pass over the output each.
+
+    The conv is stated in NHWC/HWIO with the NCHW/OIHW tensors
+    transposed around it.  The TPU's layout assignment absorbs the
+    transposes (the program matches an NCHW statement's but for the
+    input pad of a padded conv), and XLA's CPU backend runs this form as
+    it is, where it rebuilds an NCHW conv without the layer's
+    ``op_name`` and ``FusedNetwork.op_layers`` would lose the conv."""
     layer = plan.layer
-    R, S = int(layer.meta["R"]), int(layer.meta["S"])
     stride = int(layer.meta["stride"])
-    N, C = x.shape[0], x.shape[1]
-    XO, YO = layer.dim("X"), layer.dim("Y")
-    acc = jnp.zeros((N, layer.dim("K"), XO, YO), jnp.float32)
-    for r in range(R):                       # R/S pinned in-block, exactly
-        for s in range(S):                   # like the Pallas twin
-            patch = jax.lax.slice(
-                x, (0, 0, r, s),
-                (N, C, r + (XO - 1) * stride + 1,
-                 s + (YO - 1) * stride + 1),
-                (1, 1, stride, stride))      # [N, C, XO, YO]
-            acc += jnp.einsum("ncxy,kc->nkxy", patch, w[:, :, r, s],
-                              precision=MATMUL_PRECISION,
-                              preferred_element_type=jnp.float32)
-    return acc
+    out = jax.lax.conv_general_dilated(
+        x.transpose(0, 2, 3, 1), w.transpose(2, 3, 1, 0),
+        window_strides=(stride, stride), padding="VALID",
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=MATMUL_PRECISION,
+        preferred_element_type=jnp.float32).transpose(0, 3, 1, 2)
+    want = (x.shape[0], layer.dim("K"), layer.dim("X"), layer.dim("Y"))
+    if out.shape != want:
+        raise ValueError(f"conv {layer.name!r}: input {x.shape} gives "
+                         f"{out.shape}, the layer wants {want}")
+    return out
 
 
 def _pool(plan: KernelPlan, x: jnp.ndarray) -> jnp.ndarray:

@@ -3,19 +3,24 @@ segment matches the interpret oracle, the whole-net executable matches
 layer-by-layer interpret, the process-wide executable cache serves
 repeat executions with zero retrace, donation never touches weights,
 and invalid plans still fail with the offending layer's name."""
+from types import SimpleNamespace
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.solver import solve
+from repro.lower import fuse
 from repro.lower import (lower_network, make_network_inputs,
                          measure_network, network_runner)
 from repro.lower.calibrate import default_hw
+from repro.lower.exec import input_extent
 from repro.lower.fuse import (FusedNetwork, cache_stats, clear_cache,
                               compiled_plan_fn, fused_runner,
                               plan_signature)
 from repro.obs.metrics import REGISTRY
+from repro.workloads.layers import conv
 from repro.workloads.nets import get_net, transformer
 
 HW = default_hw()
@@ -128,6 +133,58 @@ def test_oracle_tolerance_fails_a_dropped_filter_tap(monkeypatch):
     wrong = verify()
     assert not wrong.ok and wrong.max_rel_err > 10 * ORACLE_TOL
     assert nplan.plans[wrong.worst_layer].kind == "conv"
+
+
+# ---------------------------------------------------------------------------
+# the compiled conv: one windowed XLA convolution per layer
+# ---------------------------------------------------------------------------
+
+CONV_CASES = [(1, 1, 1, 64), (1, 1, 2, 64), (3, 3, 1, 16), (3, 3, 2, 16),
+              (7, 7, 2, 3), (11, 11, 4, 3)]
+
+
+def _conv_case(R, S, stride, C, N=2, K=8, X=5, Y=4):
+    """A conv layer's plan stand-in (``_conv`` reads only ``plan.layer``)
+    and seeded operands at the layer's adapted input extent."""
+    layer = conv("c", N, C, K, X, Y, R, S, stride=stride)
+    XI, YI = input_extent(layer)
+    kx, kw = jax.random.split(jax.random.PRNGKey(R * 100 + stride * 10 + C))
+    x = jax.random.normal(kx, (N, C, XI, YI), jnp.float32)
+    w = jax.random.normal(kw, (K, C, R, S), jnp.float32)
+    return SimpleNamespace(layer=layer), x, w
+
+
+@pytest.mark.parametrize("R,S,stride,C", CONV_CASES,
+                         ids=[f"{r}x{s}s{st}c{c}"
+                              for r, s, st, c in CONV_CASES])
+def test_compiled_conv_matches_a_per_tap_formula(R, S, stride, C):
+    """``fuse._conv`` against the tap loop it replaced: a strided slice
+    of the input per filter tap, contracted with that tap's [K, C]
+    weights and summed, in float64."""
+    plan, x, w = _conv_case(R, S, stride, C)
+    X, Y = plan.layer.dim("X"), plan.layer.dim("Y")
+    xs, ws = np.asarray(x, np.float64), np.asarray(w, np.float64)
+    want = np.zeros((x.shape[0], w.shape[0], X, Y))
+    for r in range(R):
+        for s in range(S):
+            patch = xs[:, :, r:r + (X - 1) * stride + 1:stride,
+                       s:s + (Y - 1) * stride + 1:stride]
+            want += np.einsum("ncxy,kc->nkxy", patch, ws[:, :, r, s])
+    got = fuse._conv(plan, x, w)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    assert _rel_err(got, want) < TOL
+
+
+def test_compiled_conv_is_one_windowed_convolution():
+    """The 7x7 stride-2 stem is one ``conv_general_dilated`` and no
+    per-tap slice; an input off the layer's extent fails at trace time."""
+    plan, x, w = _conv_case(7, 7, 2, 3)
+    prims = [e.primitive.name for e in jax.make_jaxpr(
+        lambda a, b: fuse._conv(plan, a, b))(x, w).jaxpr.eqns]
+    assert prims.count("conv_general_dilated") == 1
+    assert "slice" not in prims
+    with pytest.raises(ValueError, match="the layer wants"):
+        fuse._conv(plan, x[:, :, 1:, 1:], w)
 
 
 # ---------------------------------------------------------------------------

@@ -4,6 +4,7 @@ compiled module (``FusedNetwork.op_layers``), the per-call host spans of
 ``netexec.run_fused``, ``obs.trace`` mirrored into the JAX profiler, and
 ``host.gc`` spans around garbage collections."""
 import gc
+import re
 
 import jax
 import pytest
@@ -11,7 +12,7 @@ import pytest
 from repro.core.solver import solve
 from repro.lower import lower_network, make_network_inputs, network_runner
 from repro.lower.calibrate import default_hw
-from repro.lower.fuse import fused_runner, hlo_op_layers
+from repro.lower.fuse import fused_runner, hlo_op_layers, input_specs
 from repro.obs import trace
 from repro.workloads.layers import LayerGraph, conv, eltwise, fc, pool
 
@@ -70,6 +71,21 @@ def test_op_layers_covers_every_layer_and_nothing_else(tiny_plan):
     assert kinds == {"conv", "pool", "eltwise", "fc"}
     assert not any(k.startswith("parameter") or k.startswith("acts")
                    or k.startswith("weights") for k in ops)
+
+
+def test_op_layers_maps_the_convolution_to_its_layer(tiny_plan):
+    """Each conv is one ``convolution`` instruction of the compiled
+    module, named back to its layer: the strided 3x3 stem to ``conv1``."""
+    fused = fused_runner(tiny_plan, cache=False)
+    ops = fused.op_layers("boundary")
+    specs = input_specs(tiny_plan)
+    text = fused._fn(("net", "boundary", False)).lower(
+        {k: v for k, v in specs.items() if not k.endswith(".W")},
+        {k: v for k, v in specs.items() if k.endswith(".W")},
+    ).compile().as_text()
+    convs = set(re.findall(r"^\s*(?:ROOT\s+)?%?([\w.-]+)\s*=\s*\S+\s+"
+                           r"convolution\(", text, re.M))
+    assert "conv1" in {ops.get(c) for c in convs}
 
 
 def test_hlo_op_layers_reads_entry_scopes_only():
