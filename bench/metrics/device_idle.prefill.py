@@ -1,0 +1,9 @@
+"""device_idle.prefill: share of the traced window in which no operation
+ran on the device, in %, from the profiler trace (``trace_reduce.py``)."""
+
+
+def read(ctx):
+    tr = ctx.get("trace")
+    if not tr:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])

@@ -358,7 +358,8 @@ def evaluate_batch(ft: FactorTable, hw: HWTemplate,
         f = fetches(ti, 1)
         repl = replication(ti, 1)
         delivered = f * repl
-        onchip = (tn == "I" and src_onchip) or (tn == "O" and dst_onchip)
+        onchip = (tn in layer.fmap_tensors and src_onchip) or \
+            (tn == "O" and dst_onchip)
         if onchip:
             gbuf_e += f * B * e_gbuf
             noc_e += delivered * B * e_hop * 2.0

@@ -259,7 +259,8 @@ def evaluate_layer(scheme: LayerScheme, hw: HWTemplate,
         f = scheme.fetches_into(t, 1)
         repl = scheme.replication(t, 1)
         delivered = f * repl
-        onchip = (t == "I" and src_onchip) or (t == "O" and dst_onchip)
+        onchip = (t in layer.fmap_tensors and src_onchip) or \
+            (t == "O" and dst_onchip)
         if onchip:
             # forwarded between neighbor node GBUFs: one extra gbuf access +
             # short NoC path instead of a DRAM round trip

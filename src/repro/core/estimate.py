@@ -60,7 +60,7 @@ def estimate_layer(layer: LayerSpec, hw: HWTemplate, nodes_assigned: int,
     for t in layer.tensors:
         sz = layer.tensor_size(t)
         gbuf_elems += sz
-        if t == "I" and src_onchip:
+        if t in layer.fmap_tensors and src_onchip:
             continue
         if t == "O" and dst_onchip:
             continue

@@ -110,7 +110,7 @@ def pack_graph(graph: LayerGraph, hw: HWTemplate) -> GraphPack:
             s_on, d_on = bool(v & 1), bool(v & 2)
             acc = 0.0
             for t in l.tensors:
-                if t == "I" and s_on:
+                if t in l.fmap_tensors and s_on:
                     continue
                 if t == "O" and d_on:
                     continue

@@ -104,9 +104,20 @@ class _State:
 def _stacking_pass(st: _State, level: int, hw: HWTemplate,
                    axis_budgets: List[int],
                    allowed_axis_dims: Tuple[Sequence[str], Sequence[str]],
-                   ) -> None:
-    """Spatially unroll dims across this level's unit array (greedy)."""
+                   first_dims: Sequence[str] = ()) -> None:
+    """Spatially unroll dims across this level's unit array (greedy).
+
+    ``first_dims`` are unrolled first, as far as the axes allow (used to
+    keep a kind's resident dims below the DRAM level)."""
     lv = st.levels[level]
+    for d in first_dims:
+        for ax in (0, 1):
+            while d in allowed_axis_dims[ax] and st.remaining(d) > 1:
+                p = smallest_prime_factor(st.remaining(d))
+                if axis_budgets[ax] < p:
+                    break
+                lv.s[d] = lv.sf(d) * p
+                axis_budgets[ax] //= p
     while True:
         grew = False
         for tname in st.ranked_tensors():
@@ -196,29 +207,39 @@ def solve_intra_layer(layer: LayerSpec, hw: HWTemplate,
     n_levels = len(hw.levels)
     st = _State(layer, n_levels)
 
+    # A kind's resident dims (``RESIDENT_DIMS``: attention's head dim, a
+    # norm's channel row) are placed first at every on-chip level and
+    # never left to the DRAM nest: its kernel needs them whole per block.
+    resident = layer.resident_dims
+
     # Level 0 (REGF): spatial mapping constrained by the PE dataflow template.
     pe_axes = _pe_axis_dims(hw)
-    _stacking_pass(st, 0, hw, list(hw.pe_array), pe_axes)
-    _caching_pass(st, 0, hw)
+    _stacking_pass(st, 0, hw, list(hw.pe_array), pe_axes, resident)
+    _caching_pass(st, 0, hw, first_dims=resident)
 
     # Level 1 (GBUF): free node parallelization within the assigned region.
     if n_levels >= 3:
         all_dims = tuple(d for d in DIMS)
-        _stacking_pass(st, 1, hw, list(constr.nodes), (all_dims, all_dims))
+        _stacking_pass(st, 1, hw, list(constr.nodes), (all_dims, all_dims),
+                       resident)
         first = tuple(layer.reduction_dims) if constr.full_reduction_onchip \
             else ()
-        _caching_pass(st, 1, hw, first_dims=first)
+        _caching_pass(st, 1, hw, first_dims=resident + first)
 
     st.finalize_leftovers()
-    if constr.full_reduction_onchip:
+    onchip = resident + (tuple(layer.reduction_dims)
+                         if constr.full_reduction_onchip else ())
+    if onchip:
         top = st.levels[-1]
-        for d in layer.reduction_dims:
-            if top.tf(d) > 1:   # pull reduction leftovers into GBUF caching
+        for d in onchip:
+            if top.tf(d) > 1:   # pull leftovers into GBUF caching
                 st.levels[-2].t[d] = st.levels[-2].tf(d) * top.tf(d)
                 top.t[d] = 1
         cap = hw.levels[-2].capacity_bytes
         if st.scheme.level_footprint_bytes(n_levels - 2) > cap:
-            bad = invalid("cannot keep reduction on-chip")
+            bad = invalid("cannot keep reduction on-chip"
+                          if constr.full_reduction_onchip
+                          else f"resident dims {resident} overflow GBUF")
             if use_cache:
                 intra_cache.put(key, None, bad)
             return None, bad

@@ -6,8 +6,11 @@ clarity over efficiency.
 """
 from __future__ import annotations
 
+from typing import Dict
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def softcap(x: jnp.ndarray, cap: float) -> jnp.ndarray:
@@ -81,6 +84,88 @@ def attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", probs,
                       v.astype(jnp.float32)).astype(q.dtype)
+
+
+def rope_ref(x: jnp.ndarray, theta: float) -> jnp.ndarray:
+    """Rotary position embedding of x [..., S, D] at positions 0..S-1:
+    pairs (i, i + D/2) rotate by pos * theta ** (-2i / D).  Angles and
+    their cos/sin are taken in float64 on the host, then rounded to
+    float32."""
+    S, D = x.shape[-2], x.shape[-1]
+    inv = theta ** (-np.arange(0, D, 2, dtype=np.float64) / D)
+    ang = np.arange(S, dtype=np.float64)[:, None] * inv[None, :]
+    cos = jnp.asarray(np.cos(ang), jnp.float32)
+    sin = jnp.asarray(np.sin(ang), jnp.float32)
+    x = x.astype(jnp.float32)
+    x1, x2 = x[..., : D // 2], x[..., D // 2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+
+def rmsnorm_ref(x: jnp.ndarray, g: jnp.ndarray, eps: float) -> jnp.ndarray:
+    """RMSNorm oracle over the last axis: x / sqrt(mean(x^2) + eps) * g."""
+    x = x.astype(jnp.float32)
+    ms = jnp.mean(x * x, axis=-1, keepdims=True)
+    return x / jnp.sqrt(ms + eps) * g.astype(jnp.float32)
+
+
+def glu_ref(x: jnp.ndarray) -> jnp.ndarray:
+    """SwiGLU product oracle: x [N, 2F] holds gate then up;
+    silu(gate) * up -> [N, F]."""
+    gate, up = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return gate * jax.nn.sigmoid(gate) * up
+
+
+def looplm_ref(x: jnp.ndarray, weights: Dict[str, jnp.ndarray], *,
+               batch: int, seq: int, heads: int, kv_heads: int,
+               head_dim: int, layers: int, steps: int, eps: float,
+               rope_theta: float) -> Dict[str, jnp.ndarray]:
+    """Model-level oracle of a looped transformer's prefill (Ouro's
+    LoopLM): ``steps`` passes of one stack of ``layers`` pre- and
+    post-normed (sandwich) blocks with the same weights, each pass ended
+    by the final RMSNorm, then the head on each sequence's last position.
+
+    x: the embedded tokens [batch * seq, hidden]; ``weights`` holds the
+    first pass's ``s0.*.W`` arrays only (fc [C, K], norm gains [C]) and
+    ``head.W``.  Returns every layer's output under the layer names of
+    ``workloads.nets.looplm``."""
+    out: Dict[str, jnp.ndarray] = {"embed": x}
+    H, KV, D = heads, kv_heads, head_dim
+
+    def heads_of(a, n):                      # [B*S, n*D] -> [B, n, S, D]
+        return a.reshape(batch, seq, n, D).transpose(0, 2, 1, 3)
+
+    h = x
+    for t in range(steps):
+        for i in range(layers):
+            p, w = f"s{t}.l{i}.", f"s0.l{i}."
+            res = h
+            a = out[p + "in_norm"] = rmsnorm_ref(h, weights[w + "in_norm.W"],
+                                                 eps)
+            qkv = out[p + "qkv"] = matmul_ref(a, weights[w + "qkv.W"])
+            q = rope_ref(heads_of(qkv[:, : H * D], H), rope_theta)
+            k = rope_ref(heads_of(qkv[:, H * D:(H + KV) * D], KV),
+                         rope_theta)
+            v = heads_of(qkv[:, (H + KV) * D:], KV)
+            att = attention_ref(q, k, v, causal=True)
+            att = out[p + "attn"] = att.transpose(0, 2, 1, 3).reshape(
+                batch * seq, H * D)
+            o = out[p + "o"] = matmul_ref(att, weights[w + "o.W"])
+            o = out[p + "attn_post_norm"] = rmsnorm_ref(
+                o, weights[w + "attn_post_norm.W"], eps)
+            h = out[p + "add1"] = res + o
+            res = h
+            f = out[p + "ffn_norm"] = rmsnorm_ref(
+                h, weights[w + "ffn_norm.W"], eps)
+            gu = out[p + "gate_up"] = matmul_ref(f, weights[w + "gate_up.W"])
+            g = out[p + "glu"] = glu_ref(gu)
+            dn = out[p + "down"] = matmul_ref(g, weights[w + "down.W"])
+            dn = out[p + "ffn_post_norm"] = rmsnorm_ref(
+                dn, weights[w + "ffn_post_norm.W"], eps)
+            h = out[p + "add2"] = res + dn
+        h = out[f"s{t}.norm"] = rmsnorm_ref(h, weights["s0.norm.W"], eps)
+    last = h.reshape(batch, seq, -1)[:, -1]
+    out["head"] = matmul_ref(last, weights["head.W"])
+    return out
 
 
 def ssd_ref(x: jnp.ndarray, dt: jnp.ndarray, a_log: jnp.ndarray,

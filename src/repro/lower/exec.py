@@ -1,7 +1,8 @@
 """Execute ``KernelPlan``s through ``pl.pallas_call``.
 
 One generic Pallas kernel per supported layer family (matmul/fc, conv,
-attention, pool, eltwise), parameterized entirely by the plan: the grid is the solver's
+attention, pool, eltwise, RMSNorm, SwiGLU product), parameterized
+entirely by the plan: the grid is the solver's
 DRAM-level loop nest (same order), the BlockSpecs carry the plan's block
 sizes and index maps, and reduction grid axes accumulate into the output
 block across revisits (initialized on the first visit, exactly like the
@@ -29,11 +30,13 @@ Notes on fidelity:
 """
 from __future__ import annotations
 
+import functools
 import time
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from ..kernels import ref
@@ -96,6 +99,8 @@ def _pallas_blocks(plan: KernelPlan):
                 ("K", (b["N"], b["C"], D), (NH, Skv, D)),
                 ("O", (b["N"], b["X"], D), (NH, Sq, D)),
                 ("stats", (b["N"], b["X"]), (NH, Sq))]
+    if plan.kind in ("norm", "glu"):
+        return [("O", (b["N"], b["C"]), (d("N"), d("C")))]
     raise ValueError(f"unsupported kind {plan.kind!r}")
 
 
@@ -316,6 +321,33 @@ def _run_eltwise(plan: KernelPlan, xs: Sequence[jnp.ndarray],
 # attention (flash-style online softmax over KV-position blocks)
 # ---------------------------------------------------------------------------
 
+@functools.lru_cache(maxsize=16)
+def rope_tables(seq: int, dim: int, theta: float
+                ) -> Tuple[np.ndarray, np.ndarray]:
+    """(cos, sin) [seq, dim // 2] of RoPE's angles pos * theta ** (-2i /
+    dim), computed in float64 on the host and rounded to float32."""
+    inv = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    ang = np.arange(seq, dtype=np.float64)[:, None] * inv[None, :]
+    return np.cos(ang).astype(np.float32), np.sin(ang).astype(np.float32)
+
+
+def rope(x: jnp.ndarray, theta: float) -> jnp.ndarray:
+    """Rotate x [..., S, D] by position (half-split pairs (i, i + D/2))."""
+    S, D = x.shape[-2], x.shape[-1]
+    cos, sin = rope_tables(S, D, float(theta))
+    x1, x2 = x[..., : D // 2], x[..., D // 2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1)
+
+
+def attention_inputs(layer, q: jnp.ndarray, k: jnp.ndarray
+                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """q and k [N, S, D] rotated by RoPE when the layer's meta sets a
+    ``rope_theta`` (unchanged otherwise)."""
+    theta = float(layer.meta.get("rope_theta", 0.0))
+    return (rope(q, theta), rope(k, theta)) if theta else (q, k)
+
+
 def _run_attention(plan: KernelPlan, q: jnp.ndarray, k: jnp.ndarray,
                    v: jnp.ndarray, interpret: bool) -> jnp.ndarray:
     layer = plan.layer
@@ -323,6 +355,9 @@ def _run_attention(plan: KernelPlan, q: jnp.ndarray, k: jnp.ndarray,
     D = layer.dim("K")
     bn, bx, bc = plan.block["N"], plan.block["X"], plan.block["C"]
     scale = D ** -0.5
+    causal = bool(layer.meta.get("causal", 0))
+    x_axis, c_axis = plan.axis_of("X"), plan.axis_of("C")
+    q, k = attention_inputs(layer, q, k)
 
     def kern(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref):
         def _init():
@@ -332,10 +367,21 @@ def _run_attention(plan: KernelPlan, q: jnp.ndarray, k: jnp.ndarray,
         _init_when(_first_visit(plan), _init)
         s = jnp.einsum("nqd,nkd->nqk", q_ref[...], k_ref[...],
                        preferred_element_type=jnp.float32) * scale
+        if causal:                  # key positions after the query's
+            ix = pl.program_id(x_axis) if x_axis >= 0 else 0
+            ic = pl.program_id(c_axis) if c_axis >= 0 else 0
+            qpos = ix * bx + jax.lax.broadcasted_iota(jnp.int32,
+                                                      (bx, bc), 0)
+            kpos = ic * bc + jax.lax.broadcasted_iota(jnp.int32,
+                                                      (bx, bc), 1)
+            keep = (kpos <= qpos + (Skv - Sq))[None]
+            s = jnp.where(keep, s, NEG_INF)
         m_prev = m_ref[...]
         m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
         alpha = jnp.exp(m_prev - m_cur)
         p = jnp.exp(s - m_cur[..., None])
+        if causal:                  # a block masked whole adds nothing
+            p = jnp.where(keep, p, 0.0)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
         m_ref[...] = m_cur
         acc_ref[...] = acc_ref[...] * alpha[..., None] + \
@@ -366,6 +412,59 @@ def _run_attention(plan: KernelPlan, q: jnp.ndarray, k: jnp.ndarray,
 
 
 # ---------------------------------------------------------------------------
+# RMSNorm (the channel row is block-resident: every grid axis is over N)
+# ---------------------------------------------------------------------------
+
+def _run_norm(plan: KernelPlan, x: jnp.ndarray, g: jnp.ndarray,
+              interpret: bool) -> jnp.ndarray:
+    layer = plan.layer
+    N, C = layer.dim("N"), layer.dim("C")
+    bn = plan.block["N"]
+    eps = float(layer.meta["eps"])
+
+    def kern(x_ref, g_ref, o_ref):
+        xb = x_ref[...]
+        ms = jnp.mean(xb * xb, axis=-1, keepdims=True)
+        o_ref[...] = xb * jax.lax.rsqrt(ms + eps) * g_ref[...]
+
+    return pl.pallas_call(
+        kern,
+        grid=_grid(plan),
+        in_specs=[pl.BlockSpec((bn, C), plan.index_map(("N", "C"))),
+                  pl.BlockSpec((1, C), plan.index_map(("*", "C")))],
+        out_specs=pl.BlockSpec((bn, C), plan.index_map(("N", "C"))),
+        out_shape=jax.ShapeDtypeStruct((N, C), jnp.float32),
+        interpret=interpret,
+    )(x, g.reshape(1, C))
+
+
+# ---------------------------------------------------------------------------
+# SwiGLU product (elementwise over [N, C]; gate and up blocked alike)
+# ---------------------------------------------------------------------------
+
+def _run_glu(plan: KernelPlan, x: jnp.ndarray,
+             interpret: bool) -> jnp.ndarray:
+    layer = plan.layer
+    N, C = layer.dim("N"), layer.dim("C")
+    bn, bc = plan.block["N"], plan.block["C"]
+    gate, up = x[:, :C], x[:, C:]
+
+    def kern(g_ref, u_ref, o_ref):
+        g = g_ref[...]
+        o_ref[...] = g * jax.nn.sigmoid(g) * u_ref[...]
+
+    spec = pl.BlockSpec((bn, bc), plan.index_map(("N", "C")))
+    return pl.pallas_call(
+        kern,
+        grid=_grid(plan),
+        in_specs=[spec, spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((N, C), jnp.float32),
+        interpret=interpret,
+    )(gate, up)
+
+
+# ---------------------------------------------------------------------------
 # Public API: inputs, execution, verification, measurement
 # ---------------------------------------------------------------------------
 
@@ -383,7 +482,7 @@ def make_inputs(plan: KernelPlan, seed: int = 0) -> Dict[str, jnp.ndarray]:
     """Deterministic dense float32 inputs matching the plan's canonical
     layouts (fc: I[N,C] W[C,K]; conv: I[N,C,XI,YI] W[K,C,R,S];
     attention: Q/K/V [N, S, D]; pool: I[N,C,XI,YI]; eltwise: A/B
-    [N,C,X,Y])."""
+    [N,C,X,Y]; norm: I[N,C] W[C]; glu: I[N,2C], gate then up)."""
     layer = plan.layer
     keys = jax.random.split(jax.random.PRNGKey(seed), 3)
     if plan.kind == "fc":
@@ -416,6 +515,14 @@ def make_inputs(plan: KernelPlan, seed: int = 0) -> Dict[str, jnp.ndarray]:
         shape = tuple(layer.dim(d) for d in ("N", "C", "X", "Y"))
         return {"A": jax.random.normal(keys[0], shape, jnp.float32),
                 "B": jax.random.normal(keys[1], shape, jnp.float32)}
+    if plan.kind == "norm":
+        N, C = layer.dim("N"), layer.dim("C")
+        return {"I": jax.random.normal(keys[0], (N, C), jnp.float32),
+                "W": 1.0 + 0.1 * jax.random.normal(keys[1], (C,),
+                                                   jnp.float32)}
+    if plan.kind == "glu":
+        return {"I": jax.random.normal(
+            keys[0], (layer.dim("N"), 2 * layer.dim("C")), jnp.float32)}
     raise ValueError(f"unsupported kind {plan.kind!r}")
 
 
@@ -454,6 +561,11 @@ def plan_runner(plan: KernelPlan, interpret: bool = True,
     elif plan.kind == "eltwise":
         names, base = ("A", "B"), \
             lambda a, b: _run_eltwise(plan, (a, b), interpret)
+    elif plan.kind == "norm":
+        names, base = ("I", "W"), \
+            lambda i, w: _run_norm(plan, i, w, interpret)
+    elif plan.kind == "glu":
+        names, base = ("I",), lambda i: _run_glu(plan, i, interpret)
     else:
         raise ValueError(f"unsupported kind {plan.kind!r}")
     fn = jax.jit(base) if jit else base
@@ -468,6 +580,18 @@ def execute_plan(plan: KernelPlan, inputs: Optional[Dict] = None,
     return run(inputs)                       # naming the layer + reason
 
 
+def attention_reference(layer, q: jnp.ndarray, k: jnp.ndarray,
+                        v: jnp.ndarray) -> jnp.ndarray:
+    """The ``kernels/ref.py`` oracle of one attention layer over [N, S,
+    D], with RoPE and the causal mask as the layer's meta says."""
+    q, k = q[:, None], k[:, None]
+    theta = float(layer.meta.get("rope_theta", 0.0))
+    if theta:
+        q, k = ref.rope_ref(q, theta), ref.rope_ref(k, theta)
+    return ref.attention_ref(q, k, v[:, None],
+                             causal=bool(layer.meta.get("causal", 0)))[:, 0]
+
+
 def reference_output(plan: KernelPlan, inputs: Dict) -> jnp.ndarray:
     """Ground truth from ``kernels/ref.py`` for the plan's layer."""
     if plan.kind == "fc":
@@ -476,15 +600,19 @@ def reference_output(plan: KernelPlan, inputs: Dict) -> jnp.ndarray:
         return ref.conv2d_ref(inputs["I"], inputs["W"],
                               stride=int(plan.layer.meta["stride"]))
     if plan.kind == "attention":
-        out = ref.attention_ref(inputs["Q"][:, None], inputs["K"][:, None],
-                                inputs["V"][:, None], causal=False)
-        return out[:, 0]
+        return attention_reference(plan.layer, inputs["Q"], inputs["K"],
+                                   inputs["V"])
     if plan.kind == "pool":
         return ref.pool2d_ref(inputs["I"], int(plan.layer.meta["R"]),
                               int(plan.layer.meta["S"]),
                               stride=int(plan.layer.meta["stride"]))
     if plan.kind == "eltwise":
         return ref.eltwise_ref(inputs["A"], inputs["B"])
+    if plan.kind == "norm":
+        return ref.rmsnorm_ref(inputs["I"], inputs["W"],
+                               float(plan.layer.meta["eps"]))
+    if plan.kind == "glu":
+        return ref.glu_ref(inputs["I"])
     raise ValueError(f"unsupported kind {plan.kind!r}")
 
 

@@ -53,8 +53,10 @@ import jax.numpy as jnp
 
 from ..kernels.backend import resolve_backend  # noqa: F401  (re-export)
 from ..obs import metrics, trace
-from .netexec import (_check_executable, _eltwise_operands, adapt_tensor,
-                      make_network_inputs, required_input_shape)
+from .exec import attention_inputs
+from .netexec import (WEIGHT_KINDS, _check_executable, _eltwise_operands,
+                      adapt_tensor, layer_input, make_network_inputs,
+                      merge_heads, required_input_shape, split_qkv)
 from .netplan import NetworkPlan
 from .plan import KernelPlan
 
@@ -68,6 +70,13 @@ _m_compile = metrics.histogram(
     "fused_compile_seconds",
     "wall clock of a fused-executable call that traced: trace, compile "
     "(or persistent-cache load) and one execution")
+_m_weights = metrics.gauge(
+    "fused_weight_arrays",
+    "weight arrays a call of the last-built fused executable is fed")
+_m_tied = metrics.gauge(
+    "fused_tied_layers",
+    "layers of the last-built fused executable that read another "
+    "layer's weights")
 
 
 # ---------------------------------------------------------------------------
@@ -140,12 +149,33 @@ def _eltwise(plan: KernelPlan, xs) -> jnp.ndarray:
 
 def _attention(plan: KernelPlan, q: jnp.ndarray, k: jnp.ndarray,
                v: jnp.ndarray) -> jnp.ndarray:
-    scale = plan.layer.dim("K") ** -0.5
+    """softmax(q k^T / sqrt(D)) v over [N, S, D], with q and k rotated by
+    RoPE and key positions after the query's masked, as the layer's meta
+    says.  The whole score matrix is formed (no causal skipping)."""
+    layer = plan.layer
+    q, k = attention_inputs(layer, q, k)
     s = jnp.einsum("nqd,nkd->nqk", q, k, precision=MATMUL_PRECISION,
-                   preferred_element_type=jnp.float32) * scale
+                   preferred_element_type=jnp.float32) \
+        * layer.dim("K") ** -0.5
+    if layer.meta.get("causal"):
+        sq, skv = s.shape[1], s.shape[2]
+        qpos = jax.lax.broadcasted_iota(jnp.int32, (sq, skv), 0)
+        kpos = jax.lax.broadcasted_iota(jnp.int32, (sq, skv), 1)
+        s = jnp.where(kpos <= qpos + (skv - sq), s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("nqk,nkd->nqd", p, v, precision=MATMUL_PRECISION,
                       preferred_element_type=jnp.float32)
+
+
+def _norm(plan: KernelPlan, x: jnp.ndarray, g: jnp.ndarray) -> jnp.ndarray:
+    ms = jnp.mean(x * x, axis=-1, keepdims=True)
+    return x * jax.lax.rsqrt(ms + float(plan.layer.meta["eps"])) * g
+
+
+def _glu(plan: KernelPlan, x: jnp.ndarray) -> jnp.ndarray:
+    c = plan.layer.dim("C")
+    gate, up = x[:, :c], x[:, c:]
+    return gate * jax.nn.sigmoid(gate) * up
 
 
 def compiled_plan_fn(plan: KernelPlan) -> Tuple[Callable, Tuple[str, ...]]:
@@ -167,6 +197,10 @@ def compiled_plan_fn(plan: KernelPlan) -> Tuple[Callable, Tuple[str, ...]]:
         return (lambda a, b: _eltwise(plan, (a, b))), ("A", "B")
     if plan.kind == "attention":
         return (lambda q, k, v: _attention(plan, q, k, v)), ("Q", "K", "V")
+    if plan.kind == "norm":
+        return (lambda i, w: _norm(plan, i, w)), ("I", "W")
+    if plan.kind == "glu":
+        return (lambda i: _glu(plan, i)), ("I",)
     raise ValueError(f"unsupported kind {plan.kind!r}")
 
 
@@ -228,21 +262,29 @@ def _layer_out(nplan: NetworkPlan, name: str, vals: Dict,
     def src_val(s: str) -> jnp.ndarray:
         return vals[s] if s in vals else feed[s]
 
-    shape = required_input_shape(layer)
     with jax.named_scope(name):
         if plan.kind == "eltwise":
             ops = _eltwise_operands(
                 [src_val(s) for s in srcs] if srcs else [feed[f"{name}.I"]],
                 layer)
             return _eltwise(plan, ops)
-        x = adapt_tensor(src_val(srcs[0]) if srcs else feed[f"{name}.I"],
-                         shape)
+        if srcs:
+            x = layer_input(layer, src_val(srcs[0]))
+        else:
+            x = adapt_tensor(feed[f"{name}.I"], required_input_shape(layer))
+        w = feed.get(f"{layer.weight_owner}.W")
         if plan.kind == "fc":
-            return _fc(plan, x, feed[f"{name}.W"])
+            return _fc(plan, x, w)
         if plan.kind == "conv":
-            return _conv(plan, x, feed[f"{name}.W"])
+            return _conv(plan, x, w)
         if plan.kind == "pool":
             return _pool(plan, x)
+        if plan.kind == "norm":
+            return _norm(plan, x, w)
+        if plan.kind == "glu":
+            return _glu(plan, x)
+        if plan.kind == "attention":
+            return merge_heads(layer, _attention(plan, *split_qkv(layer, x)))
     raise ValueError(f"cannot execute layer {name!r}: kind "
                      f"{plan.kind!r} has no network-exec input feed")
 
@@ -291,14 +333,19 @@ def _segment_io(nplan: NetworkPlan, seg) -> Tuple[Tuple[str, ...],
             consumes += [s for s in srcs if s not in inseg]
         else:
             consumes.append(f"{n}.I")
-        if layer.kind in ("fc", "conv"):
-            consumes.append(f"{n}.W")
+        if layer.kind in WEIGHT_KINDS:
+            consumes.append(f"{layer.weight_owner}.W")
     produces = []
     for n in seg.layer_names:
         cons = nplan.placements[n].consumers
         if not cons or any(c not in inseg for c in cons):
             produces.append(n)
     return tuple(dict.fromkeys(consumes)), tuple(produces)
+
+
+#: what a whole-net call returns: every layer's output, the segment
+#: boundaries and network outputs, or the graph's sink outputs alone
+KEEPS = ("all", "boundary", "outputs")
 
 
 class FusedNetwork:
@@ -314,6 +361,10 @@ class FusedNetwork:
     ``fuse.compile`` covers the first call of a jitted variant (trace,
     compile or cache load, one run) and ``fuse.dispatch`` every later
     call, until the jitted call returns.
+
+    Building one sets the gauges ``fused_weight_arrays`` (the ``.W``
+    arrays a call is fed: one per weight owner) and ``fused_tied_layers``
+    (the layers that read another layer's weights).
     """
 
     def __init__(self, nplan: NetworkPlan):
@@ -326,6 +377,13 @@ class FusedNetwork:
         self._lock = threading.Lock()
         self.segment_io = [_segment_io(nplan, seg)
                            for seg in nplan.segments]
+        layers = [nplan.plans[n].layer for n in nplan.order]
+        self.weight_arrays = len({l.weight_owner for l in layers
+                                  if l.kind in WEIGHT_KINDS})
+        self.tied_layers = sum(l.weight_owner != l.name for l in layers
+                               if l.kind in WEIGHT_KINDS)
+        _m_weights.set(self.weight_arrays)
+        _m_tied.set(self.tied_layers)
 
     # -- builders -----------------------------------------------------------
 
@@ -336,6 +394,9 @@ class FusedNetwork:
         nplan = self.nplan
         if keep == "all":
             kept = list(nplan.order)
+        elif keep == "outputs":              # the graph's sinks only
+            kept = [n for n in nplan.order
+                    if not nplan.placements[n].consumers]
         else:                                # "boundary": serving outputs
             kept = [n for s in self.segment_io for n in s[1]]
 
@@ -391,19 +452,23 @@ class FusedNetwork:
             _m_compile.observe(time.perf_counter() - t0)
         return out
 
-    def op_layers(self, keep: str = "boundary") -> Dict[str, str]:
-        """``{HLO instruction: layer}`` of the whole-net executable (the
-        ``keep`` variant, not donating), read from its compiled text: the
-        join key between a device trace's ``XLA Ops`` events, which are
-        named by instruction, and the plan's layers.  Lowers and compiles
-        the variant (a cache load when it was compiled before); a
-        set-up-time call, never on the serving path."""
+    def compiled_text(self, keep: str = "boundary") -> str:
+        """The compiled module's text of the whole-net executable (the
+        ``keep`` variant, not donating).  Lowers and compiles the variant
+        (a cache load when it was compiled before); a set-up-time call,
+        never on the serving path."""
         specs = input_specs(self.nplan)
         acts = {k: v for k, v in specs.items() if not k.endswith(".W")}
         weights = {k: v for k, v in specs.items() if k.endswith(".W")}
-        text = self._fn(("net", keep, False)).lower(
+        return self._fn(("net", keep, False)).lower(
             acts, weights).compile().as_text()
-        return hlo_op_layers(text, self.nplan.order)
+
+    def op_layers(self, keep: str = "boundary") -> Dict[str, str]:
+        """``{HLO instruction: layer}`` of the whole-net executable, read
+        from its compiled text (``compiled_text``): the join key between
+        a device trace's ``XLA Ops`` events, which are named by
+        instruction, and the plan's layers."""
+        return hlo_op_layers(self.compiled_text(keep), self.nplan.order)
 
     # -- execution ----------------------------------------------------------
 
@@ -412,11 +477,13 @@ class FusedNetwork:
         """Run the whole plan as one executable.  ``keep="all"`` returns
         every layer output (verification); ``keep="boundary"`` returns
         only segment-boundary/network outputs (the serving path —
-        forwarded tensors never materialize).  ``donate=True`` donates
-        the external activation buffers (weights are never donated);
-        donated inputs must not be reused by the caller."""
-        if keep not in ("all", "boundary"):
-            raise ValueError(f"keep must be 'all'|'boundary', got {keep!r}")
+        forwarded tensors never materialize); ``keep="outputs"`` returns
+        the graph's sinks alone (a served prefill's logits, where the
+        boundary tensors would not fit the device).  ``donate=True``
+        donates the external activation buffers (weights are never
+        donated); donated inputs must not be reused by the caller."""
+        if keep not in KEEPS:
+            raise ValueError(f"keep must be one of {KEEPS}, got {keep!r}")
         with trace.span("fuse.feed"):
             acts = {k: v for k, v in inputs.items()
                     if not k.endswith(".W")}

@@ -26,6 +26,15 @@ identically by the executor and the whole-graph reference pass
 and multi-source eltwise layers whose channel counts partition the output
 (inception concat) embed each source at its channel offset, so the n-ary
 sum kernel computes the concatenation.
+
+Token-major layers (a transformer's fc, norm, glu and eltwise layers over
+[batch * seq, width]) need three more steps, each part of its layer:
+an attention layer splits Q, K and V out of its ``qkv`` source
+(``split_qkv``) and hands its output back token-major (``merge_heads``);
+an fc with ``meta["last_position"]`` reads each sequence's last token
+(``last_positions``).  A layer with ``meta["tied"]`` reads the weights
+fed for the layer it names (``LayerSpec.weight_owner``), so a looped
+stack's weights are fed once.
 """
 from __future__ import annotations
 
@@ -43,9 +52,9 @@ from ..kernels import ref
 from ..kernels.backend import backend_interprets, resolve_backend
 from ..obs import metrics, trace, watch
 from ..workloads.layers import LayerSpec
-from .exec import (ORACLE_TOL, _check_compiled_pallas, _run_conv,
-                   _run_eltwise, _run_fc, _run_pool, input_extent,
-                   rel_error)
+from .exec import (ORACLE_TOL, _check_compiled_pallas, _run_attention,
+                   _run_conv, _run_eltwise, _run_fc, _run_glu, _run_norm,
+                   _run_pool, attention_reference, input_extent, rel_error)
 from .netplan import NetworkPlan
 
 
@@ -54,10 +63,27 @@ from .netplan import NetworkPlan
 # ---------------------------------------------------------------------------
 
 
+#: kinds fed a ``<owner>.W`` weight array
+WEIGHT_KINDS = ("conv", "fc", "norm")
+
+
+def _heads(layer: LayerSpec) -> Tuple[int, int]:
+    """(query heads, K/V heads) of an attention layer."""
+    h = int(layer.meta["heads"])
+    return h, int(layer.meta.get("kv_heads", h))
+
+
 def required_input_shape(layer: LayerSpec) -> Tuple[int, ...]:
-    """Canonical input-activation shape each kernel consumes."""
-    if layer.kind == "fc":
+    """Canonical input-activation shape each kernel consumes (for an
+    attention layer, its ``qkv`` source's token-major output)."""
+    if layer.kind in ("fc", "norm"):
         return (layer.dim("N"), layer.dim("C"))
+    if layer.kind == "glu":
+        return (layer.dim("N"), 2 * layer.dim("C"))
+    if layer.kind == "attention":
+        h, kv = _heads(layer)
+        return (int(layer.meta["batch"]) * layer.dim("X"),
+                (h + 2 * kv) * layer.dim("K"))
     if layer.kind in ("conv", "pool"):
         XI, YI = input_extent(layer)
         return (layer.dim("N"), layer.dim("C"), XI, YI)
@@ -97,6 +123,45 @@ def adapt_tensor(arr: jnp.ndarray, shape: Tuple[int, ...]) -> jnp.ndarray:
                      f"{tuple(shape)}")
 
 
+def last_positions(arr: jnp.ndarray, seq: int) -> jnp.ndarray:
+    """Each sequence's last position of a token-major [batch * seq, ...]
+    tensor: [batch, ...]."""
+    return arr.reshape((-1, seq) + tuple(arr.shape[1:]))[:, -1]
+
+
+def layer_input(layer: LayerSpec, arr: jnp.ndarray) -> jnp.ndarray:
+    """A producer's output as the layer's kernel consumes it: the last
+    positions first where the layer asks for them, then the adapter."""
+    if "last_position" in layer.meta:
+        arr = last_positions(arr, int(layer.meta["last_position"]))
+    return adapt_tensor(arr, required_input_shape(layer))
+
+
+def split_qkv(layer: LayerSpec, x: jnp.ndarray
+              ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Q, K and V [batch * heads, seq, head_dim] out of a ``qkv`` output
+    [batch * seq, (heads + 2 * kv_heads) * head_dim] (per token: the
+    query heads, then the key heads, then the value heads); K/V heads
+    repeat over their query heads."""
+    h, kv = _heads(layer)
+    b, s, d = int(layer.meta["batch"]), layer.dim("X"), layer.dim("K")
+    t = x.reshape(b, s, h + 2 * kv, d).transpose(0, 2, 1, 3)
+    q, k, v = t[:, :h], t[:, h:h + kv], t[:, h + kv:]
+    if kv != h:
+        k = jnp.repeat(k, h // kv, axis=1)
+        v = jnp.repeat(v, h // kv, axis=1)
+    return tuple(a.reshape(b * h, s, d) for a in (q, k, v))
+
+
+def merge_heads(layer: LayerSpec, o: jnp.ndarray) -> jnp.ndarray:
+    """[batch * heads, seq, head_dim] -> token-major [batch * seq,
+    heads * head_dim]."""
+    h, _ = _heads(layer)
+    b, s, d = int(layer.meta["batch"]), layer.dim("X"), layer.dim("K")
+    return o.reshape(b, h, s, d).transpose(0, 2, 1, 3).reshape(b * s,
+                                                                h * d)
+
+
 def _eltwise_operands(srcs: Sequence[jnp.ndarray],
                       layer: LayerSpec) -> List[jnp.ndarray]:
     """Adapt eltwise sources to the output shape.  When the sources'
@@ -132,7 +197,9 @@ def make_network_inputs(nplan: NetworkPlan,
                         seed: int = 0) -> Dict[str, jnp.ndarray]:
     """``"<layer>.I"`` external activations for graph sources and
     ``"<layer>.W"`` weights for conv/fc layers, variance-scaled so
-    activations stay O(1) through deep graphs."""
+    activations stay O(1) through deep graphs, and norm gains near 1.  A
+    layer that reads another's weights (``meta["tied"]``) is fed none of
+    its own."""
     inputs: Dict[str, jnp.ndarray] = {}
     for name in nplan.order:
         layer = nplan.plans[name].layer
@@ -140,7 +207,12 @@ def make_network_inputs(nplan: NetworkPlan,
             inputs[f"{name}.I"] = jax.random.normal(
                 _key(seed, name + ".I"), required_input_shape(layer),
                 jnp.float32)
-        if layer.kind == "fc":
+        if layer.weight_owner != name:
+            continue
+        if layer.kind == "norm":
+            inputs[f"{name}.W"] = 1.0 + 0.1 * jax.random.normal(
+                _key(seed, name + ".W"), (layer.dim("C"),), jnp.float32)
+        elif layer.kind == "fc":
             inputs[f"{name}.W"] = jax.random.normal(
                 _key(seed, name + ".W"),
                 (layer.dim("C"), layer.dim("K")), jnp.float32) \
@@ -166,14 +238,28 @@ def _layer_fn(nplan: NetworkPlan, name: str, inputs: Dict,
     plan = nplan.plans[name]
     layer = plan.layer
     srcs = tuple(s for s in layer.src if s in nplan.plans)
-    w = inputs.get(f"{name}.W")
+    w = inputs.get(f"{layer.weight_owner}.W")
     ext = inputs.get(f"{name}.I")
     shape = required_input_shape(layer)
 
     if plan.kind == "fc":
         def fn(*xs):
-            return _run_fc(plan, adapt_tensor(xs[0] if xs else ext, shape),
+            return _run_fc(plan, layer_input(layer, xs[0] if xs else ext),
                            w, interpret)
+    elif plan.kind == "norm":
+        def fn(*xs):
+            return _run_norm(plan, layer_input(layer, xs[0] if xs else ext),
+                             w, interpret)
+    elif plan.kind == "glu":
+        def fn(*xs):
+            return _run_glu(plan, layer_input(layer, xs[0] if xs else ext),
+                            interpret)
+    elif plan.kind == "attention":
+        def fn(*xs):
+            q, k, v = split_qkv(layer, layer_input(layer,
+                                                   xs[0] if xs else ext))
+            return merge_heads(layer, _run_attention(plan, q, k, v,
+                                                     interpret))
     elif plan.kind == "conv":
         def fn(*xs):
             return _run_conv(plan, adapt_tensor(xs[0] if xs else ext,
@@ -236,8 +322,9 @@ def network_runner(nplan: NetworkPlan, inputs: Dict,
       * ``"compiled"`` — fused segments (``fuse.fused_runner``): the
         whole plan runs as one jitted executable from the process-wide
         executable cache; ``keep="boundary"`` returns only segment-
-        boundary outputs (the serving/measurement path), ``keep="all"``
-        every layer output (verification).
+        boundary outputs (the serving/measurement path), ``keep="outputs"``
+        only the graph's sink outputs, ``keep="all"`` every layer output
+        (verification).
     """
     backend = resolve_backend(backend, interpret)
     if backend == "compiled":
@@ -318,12 +405,19 @@ def reference_network(nplan: NetworkPlan,
     for name in nplan.order:
         layer = nplan.plans[name].layer
         srcs = [vals[s] for s in layer.src if s in vals]
-        shape = required_input_shape(layer)
-        x = adapt_tensor(srcs[0], shape) if srcs else inputs[f"{name}.I"]
+        x = layer_input(layer, srcs[0]) if srcs else inputs[f"{name}.I"]
+        w = inputs.get(f"{layer.weight_owner}.W")
         if layer.kind == "fc":
-            vals[name] = ref.matmul_ref(x, inputs[f"{name}.W"])
+            vals[name] = ref.matmul_ref(x, w)
+        elif layer.kind == "norm":
+            vals[name] = ref.rmsnorm_ref(x, w, float(layer.meta["eps"]))
+        elif layer.kind == "glu":
+            vals[name] = ref.glu_ref(x)
+        elif layer.kind == "attention":
+            vals[name] = merge_heads(
+                layer, attention_reference(layer, *split_qkv(layer, x)))
         elif layer.kind == "conv":
-            vals[name] = ref.conv2d_ref(x, inputs[f"{name}.W"],
+            vals[name] = ref.conv2d_ref(x, w,
                                         stride=int(layer.meta["stride"]))
         elif layer.kind == "pool":
             vals[name] = ref.pool2d_ref(x, int(layer.meta["R"]),

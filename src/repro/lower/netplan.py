@@ -37,9 +37,10 @@ from ..workloads.layers import LayerGraph
 from ..core.solver.interlayer import _consumer_map
 from .plan import KernelPlan, lower_scheme
 
-#: kinds the network executor can feed from predecessor outputs (attention
-#: layers take Q/K/V triples, which layer graphs do not model as edges)
-NETWORK_EXEC_KINDS = ("conv", "fc", "pool", "eltwise")
+#: kinds the network executor can feed from predecessor outputs (an
+#: attention layer splits Q, K and V out of its ``qkv`` source's output)
+NETWORK_EXEC_KINDS = ("conv", "fc", "pool", "eltwise", "attention", "norm",
+                      "glu")
 
 
 @dataclasses.dataclass(frozen=True)

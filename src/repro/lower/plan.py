@@ -33,7 +33,8 @@ from ..workloads.layers import DIMS, LayerSpec
 from ..core.cost_model import CostBreakdown, evaluate_layer
 from ..core.directives import LayerScheme, smallest_prime_factor
 
-SUPPORTED_KINDS = ("conv", "fc", "attention", "pool", "eltwise")
+SUPPORTED_KINDS = ("conv", "fc", "attention", "pool", "eltwise", "norm",
+                   "glu")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -48,7 +49,7 @@ class KernelPlan:
 
     layer: LayerSpec
     scheme: LayerScheme            # the (possibly repaired) scheme executed
-    kind: str                      # conv | fc | attention
+    kind: str                      # a ``SUPPORTED_KINDS`` entry
     grid: Tuple[GridAxis, ...]     # outer -> inner
     block: Dict[str, int]          # per-dim on-chip block size per grid step
     valid: bool
@@ -187,6 +188,10 @@ def lower_scheme(scheme: LayerScheme, hw: HWTemplate,
                             "attention head-dim split at DRAM level "
                             "(K rows must stay block-resident)")
         scheme = reshaped
+    split = [d for d in layer.resident_dims if scheme.levels[-1].tf(d) > 1]
+    if split:
+        return _invalid(scheme, kind, f"{kind} dims {split} split at DRAM "
+                        "level (they must stay block-resident)")
 
     top = scheme.levels[-1]
     block: Dict[str, int] = {}

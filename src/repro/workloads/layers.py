@@ -21,6 +21,12 @@ from typing import Dict, FrozenSet, List, Mapping, Optional, Sequence, Tuple
 
 DIMS = ("N", "C", "K", "X", "Y")
 
+#: dims a kind's kernel needs whole inside one on-chip block, never split
+#: by the DRAM-level loop nest: attention's head dim (softmax statistics
+#: are per query row and the PV product consumes whole rows) and a norm's
+#: channel row (its mean square)
+RESIDENT_DIMS = {"attention": ("K",), "norm": ("C",)}
+
 
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
@@ -53,9 +59,12 @@ class LayerSpec:
     unit_inner: Optional[Mapping[str, float]] = None
     # kind-specific execution parameters needed to *run* the layer (the
     # analytic model folds them into ``unit``/``macs_per_point``): R, S and
-    # stride for conv-family layers, causal for attention.  Excluded from
-    # the solver memo signature — it only affects lowering/execution.
-    meta: Mapping[str, float] = dataclasses.field(default_factory=dict)
+    # stride for conv-family layers; causal, RoPE theta and head counts for
+    # attention; eps for a norm; ``last_position`` for an fc that reads
+    # each sequence's last token; ``tied``, the name of the layer whose
+    # weights this one reads.  Excluded from the solver memo signature —
+    # it only affects lowering/execution.
+    meta: Mapping[str, object] = dataclasses.field(default_factory=dict)
 
     def inner_unit(self, t: str) -> float:
         u = self.unit_inner if self.unit_inner is not None else self.unit
@@ -87,6 +96,23 @@ class LayerSpec:
     @property
     def weight_tensor(self) -> Optional[str]:
         return "W" if "W" in self.tensors else None
+
+    @property
+    def fmap_tensors(self) -> Tuple[str, ...]:
+        """Tensors fed by the layer's sources: the input fmap, and for
+        attention also the K/V pair its ``qkv`` source produces — priced
+        as a forwarded activation, not as a weight fetched from DRAM."""
+        return ("I", "W") if self.kind == "attention" else ("I",)
+
+    @property
+    def resident_dims(self) -> Tuple[str, ...]:
+        return RESIDENT_DIMS.get(self.kind, ())
+
+    @property
+    def weight_owner(self) -> str:
+        """The layer whose weights this one reads: itself, or the layer
+        named by ``meta["tied"]`` (a looped stack's later steps)."""
+        return str(self.meta.get("tied", self.name))
 
     def footprint_bytes(self) -> float:
         return sum(self.tensor_size(t) for t in self.tensors) * self.bytes_per_elem
@@ -129,7 +155,8 @@ class LayerSpec:
             meta=dict(d.get("meta", {})))
 
     def ifmap_size(self) -> float:
-        return self.tensor_size("I") if "I" in self.tensors else 0.0
+        return sum(self.tensor_size(t) for t in self.fmap_tensors
+                   if t in self.tensors)
 
 
 def conv(name: str, n: int, c: int, k: int, xo: int, yo: int, r: int, s: int,
@@ -203,7 +230,9 @@ def pool(name: str, n: int, c: int, xo: int, yo: int, r: int, s: int,
 
 def attention(name: str, batch: int, heads: int, seq_q: int, d_head: int,
               seq_kv: Optional[int] = None,
-              src: Sequence[str] = ()) -> LayerSpec:
+              src: Sequence[str] = (), causal: bool = False,
+              rope_theta: float = 0.0,
+              kv_heads: Optional[int] = None) -> LayerSpec:
     """Fused attention scores+context op (softmax(QK^T) V) for one head
     group, in solver-generic form.
 
@@ -213,8 +242,22 @@ def attention(name: str, batch: int, heads: int, seq_q: int, d_head: int,
     operands stream together); O [N, X, K].  Two MACs per point of the
     N x X x C x K space (QK^T and PV).  The scores/probs matrix never
     appears as a tensor — like flash attention, it lives within a block.
+
+    Fed from a graph edge (``src``: a ``qkv`` fc of width
+    (heads + 2 * kv_heads) * d_head per token), the executor splits Q, K
+    and V out of the source and returns [batch * seq, heads * d_head];
+    K/V heads repeat over ``heads // kv_heads`` query heads.  ``causal``
+    masks key positions after the query's; ``rope_theta`` > 0 rotates q
+    and k by their positions (RoPE, half-split pairs) first.
     """
     skv = seq_kv if seq_kv is not None else seq_q
+    meta = {"batch": batch, "heads": heads}
+    if causal:
+        meta["causal"] = 1
+    if rope_theta:
+        meta["rope_theta"] = float(rope_theta)
+    if kv_heads is not None:
+        meta["kv_heads"] = kv_heads
     return LayerSpec(
         name=name, kind="attention",
         dims={"N": batch * heads, "X": seq_q, "C": skv, "K": d_head},
@@ -225,7 +268,40 @@ def attention(name: str, batch: int, heads: int, seq_q: int, d_head: int,
         macs_per_point=2.0,
         reduction_dims=frozenset({"C"}),
         src=tuple(src),
-        meta={"batch": batch, "heads": heads})
+        meta=meta)
+
+
+def rmsnorm(name: str, n: int, c: int, eps: float,
+            src: Sequence[str] = ()) -> LayerSpec:
+    """RMSNorm over the C channels of each of N rows, with a gain of [C]:
+    x * rsqrt(mean(x^2) + eps) * g.  The gain is the layer's weight; the
+    row's mean square needs C whole in one block (``RESIDENT_DIMS``)."""
+    return LayerSpec(
+        name=name, kind="norm",
+        dims={"N": n, "C": c},
+        tensors={"I": frozenset({"N", "C"}),
+                 "W": frozenset({"C"}),
+                 "O": frozenset({"N", "C"})},
+        unit={"I": 1.0, "W": 1.0, "O": 1.0},
+        macs_per_point=2.0,                  # square-accumulate, scale
+        reduction_dims=frozenset(),
+        src=tuple(src),
+        meta={"eps": float(eps)})
+
+
+def glu(name: str, n: int, c: int, src: Sequence[str] = ()) -> LayerSpec:
+    """Gated product of a SwiGLU FFN: silu(gate) * up over [N, C], where
+    the source's rows hold gate then up ([N, 2C], a fused ``gate_up``
+    fc).  No weights."""
+    return LayerSpec(
+        name=name, kind="glu",
+        dims={"N": n, "C": c},
+        tensors={"I": frozenset({"N", "C"}),
+                 "O": frozenset({"N", "C"})},
+        unit={"I": 2.0, "O": 1.0},           # gate and up
+        macs_per_point=1.0,
+        reduction_dims=frozenset(),
+        src=tuple(src), has_weights=False)
 
 
 def eltwise(name: str, n: int, c: int, xo: int, yo: int,

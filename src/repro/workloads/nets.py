@@ -1,13 +1,19 @@
-"""The seven evaluated networks from KAPLA §V (Methodology).
+"""Layer graphs: the seven evaluated networks from KAPLA §V (Methodology),
+a transformer-style fc chain and a looped transformer.
 
-AlexNet, MobileNet, VGGNet(-16), GoogLeNet, ResNet(-50), an MLP, and an LSTM.
-Default batch 64 (paper), batch 1 for edge inference.
+AlexNet, MobileNet, VGGNet(-16), GoogLeNet, ResNet(-50), an MLP, and an
+LSTM, at default batch 64 (paper), batch 1 for edge inference; then
+``transformer`` (fc and eltwise blocks, the inter-layer DP's deep case)
+and ``looplm`` (a looped language model's prefill with attention, RMSNorm
+and SwiGLU layers, weights shared across loop steps).
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import List
 
-from .layers import LayerGraph, LayerSpec, conv, dwconv, eltwise, fc, pool
+from .layers import (LayerGraph, LayerSpec, attention, conv, dwconv, eltwise,
+                     fc, glu, pool, rmsnorm)
 
 
 def alexnet(batch: int = 64) -> LayerGraph:
@@ -183,10 +189,10 @@ def transformer(batch: int = 64, layers: int = 12, d_model: int = 512,
     Per block: a fused QKV projection, the attention output projection, a
     residual add, the two FFN GEMMs, and a second residual add — six layers
     per block, so the inter-layer DP (segment slicing across hundreds of
-    layers) dominates the solve on deep configs.  Attention score/context
-    matmuls are activation-activation products the generic layer model has
-    no tensor class for; the GEMM chain above carries the inter-layer
-    structure (long residual-linked pipelines) that the solver exercises.
+    layers) dominates the solve on deep configs.  It leaves out the
+    attention products and norms, which ``looplm`` has as layers of their
+    own; the GEMM chain above carries the inter-layer structure (long
+    residual-linked pipelines) that the solver exercises.
     """
     L: List[LayerSpec] = []
     prev = ""
@@ -206,6 +212,82 @@ def transformer(batch: int = 64, layers: int = 12, d_model: int = 512,
     return LayerGraph(f"transformer{layers}", L)
 
 
+#: parts of one block of ``looplm``, in graph order
+LOOPLM_PARTS = ("in_norm", "qkv", "attn", "o", "attn_post_norm", "add1",
+                "ffn_norm", "gate_up", "glu", "down", "ffn_post_norm",
+                "add2")
+
+
+def looplm(batch: int = 1, seq: int = 4096, hidden: int = 2048,
+           heads: int = 16, kv_heads: int = 16, head_dim: int = 128,
+           ffn: int = 5632, layers: int = 48, steps: int = 4,
+           vocab: int = 49152, eps: float = 1e-6,
+           rope_theta: float = 1e6) -> LayerGraph:
+    """A looped language model's prefill (Ouro's LoopLM, ByteDance Seed,
+    "Scaling Latent Reasoning via Looped Language Models"): one stack of
+    ``layers`` blocks run ``steps`` times with the same weights, every
+    step run (no early exit).
+
+    Tokens are the rows: fc, norm, glu and eltwise layers work on
+    [batch * seq, width].  Block i of step t, layers ``s{t}.l{i}.<part>``
+    (``LOOPLM_PARTS``), with sandwich normalisation (an RMSNorm before and
+    after each sub-layer): ``in_norm``; ``qkv`` fc to
+    (heads + 2 * kv_heads) * head_dim; ``attn``, causal over ``seq``
+    positions with RoPE on q and k; ``o`` fc back to ``hidden``;
+    ``attn_post_norm``; ``add1``, the residual; ``ffn_norm``; ``gate_up``
+    fc to 2 * ffn; ``glu``, silu(gate) * up; ``down`` fc; ``ffn_post_norm``;
+    ``add2``.  Each step ends in the stack's final RMSNorm, ``s{t}.norm``,
+    whose output feeds step t+1.  ``head`` (fc to ``vocab``) reads each
+    sequence's last position after the last step.
+
+    The embedded tokens enter through ``embed``, a one-operand eltwise
+    layer (the identity): the first block reads them twice, in its norm
+    and its residual, and a layer graph feeds an external input to a
+    layer without sources only.  Every layer of step t >= 1 names its
+    step-0 twin in ``meta["tied"]``: its weights are fed once and read by
+    every step, while the solver still prices each step's weight fetch.
+    """
+    n = batch * seq
+    qkv_width = (heads + 2 * kv_heads) * head_dim
+    L: List[LayerSpec] = [eltwise("embed", n, hidden, 1, 1)]
+    prev = "embed"
+    for t in range(steps):
+        step: List[LayerSpec] = []
+        for i in range(layers):
+            p = f"s{t}.l{i}."
+            step += [
+                rmsnorm(p + "in_norm", n, hidden, eps, src=[prev]),
+                fc(p + "qkv", n, hidden, qkv_width, src=[p + "in_norm"]),
+                attention(p + "attn", batch, heads, seq, head_dim,
+                          src=[p + "qkv"], causal=True,
+                          rope_theta=rope_theta, kv_heads=kv_heads),
+                fc(p + "o", n, heads * head_dim, hidden, src=[p + "attn"]),
+                rmsnorm(p + "attn_post_norm", n, hidden, eps,
+                        src=[p + "o"]),
+                eltwise(p + "add1", n, hidden, 1, 1,
+                        src=[p + "attn_post_norm", prev]),
+                rmsnorm(p + "ffn_norm", n, hidden, eps, src=[p + "add1"]),
+                fc(p + "gate_up", n, hidden, 2 * ffn, src=[p + "ffn_norm"]),
+                glu(p + "glu", n, ffn, src=[p + "gate_up"]),
+                fc(p + "down", n, ffn, hidden, src=[p + "glu"]),
+                rmsnorm(p + "ffn_post_norm", n, hidden, eps,
+                        src=[p + "down"]),
+                eltwise(p + "add2", n, hidden, 1, 1,
+                        src=[p + "ffn_post_norm", p + "add1"]),
+            ]
+            prev = p + "add2"
+        step.append(rmsnorm(f"s{t}.norm", n, hidden, eps, src=[prev]))
+        prev = f"s{t}.norm"
+        if t:
+            step = [dataclasses.replace(
+                l, meta={**l.meta, "tied": f"s0.{l.name.split('.', 1)[1]}"})
+                for l in step]
+        L += step
+    head = fc("head", batch, hidden, vocab, src=[prev])
+    L.append(dataclasses.replace(head, meta={"last_position": seq}))
+    return LayerGraph(f"looplm{layers}x{steps}", L)
+
+
 NETS = {
     "alexnet": alexnet,
     "mobilenet": mobilenet,
@@ -215,9 +297,13 @@ NETS = {
     "mlp": mlp,
     "lstm": lstm,
     "transformer": transformer,
+    "looplm": looplm,
 }
 
 
-def get_net(name: str, batch: int = 64, training: bool = False) -> LayerGraph:
-    g = NETS[name](batch)
+def get_net(name: str, batch: int = 64, training: bool = False,
+            **sizes) -> LayerGraph:
+    """The named net at ``batch``; ``sizes`` go to its builder as they
+    are (``looplm``'s seq, hidden, heads, ...)."""
+    g = NETS[name](batch, **sizes)
     return g.training_graph() if training else g

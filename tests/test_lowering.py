@@ -15,7 +15,8 @@ from repro.lower import (execute_plan, lower_scheme, lower_schedule,
                          verify_plan)
 from repro.lower.calibrate import (default_hw, run_calibration,
                                    scheme_variants, spearman)
-from repro.workloads.layers import attention, conv, dwconv, eltwise, fc, pool
+from repro.workloads.layers import (attention, conv, dwconv, eltwise, fc,
+                                    glu, pool, rmsnorm)
 from repro.workloads.nets import get_net
 
 # small node grid so realistic layers overflow on-chip capacity and the
@@ -38,6 +39,9 @@ SWEEP = [
     conv("t.conv.str2", 2, 32, 64, 28, 28, 3, 3, stride=2),
     attention("t.attn.s", 2, 2, 128, 64),
     attention("t.attn.m", 2, 4, 256, 64),
+    attention("t.attn.causal", 2, 4, 256, 64, causal=True, rope_theta=1e4),
+    rmsnorm("t.norm", 64, 512, 1e-6),
+    glu("t.glu", 64, 384),
     pool("t.pool.s", 2, 16, 13, 13, 3, 3),
     pool("t.pool.str", 1, 96, 27, 27, 3, 3, stride=2),
     eltwise("t.elt.s", 2, 64, 14, 14),
@@ -94,11 +98,13 @@ def test_footprint_validity_rejects_overflow():
 def test_attention_head_dim_split_is_repaired():
     layer = attention("t.attn.split", 2, 2, 128, 64)
     scheme = _best_scheme(layer)
-    # force a head-dim split at the DRAM level
+    # the solver keeps the head dim below the DRAM level: force a split
+    # there by moving a factor 2 of K out of an on-chip level's blocking
+    assert scheme.levels[-1].tf("K") == 1
     split = LayerScheme(layer, [lv.copy() for lv in scheme.levels])
-    gbuf, top = split.levels[-2], split.levels[-1]
-    assert gbuf.tf("K") % 2 == 0, "test premise: K blocked on-chip"
-    gbuf.t["K"] = gbuf.tf("K") // 2
+    top = split.levels[-1]
+    held = next(lv for lv in split.levels[:-1] if lv.tf("K") % 2 == 0)
+    held.t["K"] = held.tf("K") // 2
     top.t["K"] = top.tf("K") * 2
     assert split.validate_factors()
     strict = lower_scheme(split, HW, repair=False)
@@ -167,7 +173,9 @@ def test_training_graph_lowers_without_crash():
 def test_layer_scheme_json_roundtrip_parity():
     for layer in (fc("t.rt.fc", 64, 512, 512),
                   conv("t.rt.conv", 2, 16, 32, 14, 14, 3, 3),
-                  attention("t.rt.attn", 2, 2, 128, 64)):
+                  attention("t.rt.attn", 2, 2, 128, 64),
+                  attention("t.rt.attn.causal", 2, 2, 128, 64, causal=True,
+                            rope_theta=1e6, kv_heads=1)):
         scheme = _best_scheme(layer)
         blob = json.dumps(scheme.to_json())
         back = LayerScheme.from_json(json.loads(blob))

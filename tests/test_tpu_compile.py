@@ -112,3 +112,31 @@ def test_pallas_backend_refuses_misaligned_plans(resnet64, one_chip):
     w = jax.ShapeDtypeStruct((2048, 1000), jnp.float32, sharding=one_chip)
     with pytest.raises(Exception, match="divisible"):
         jax.jit(lambda a, b: _run_fc(fc, a, b, False)).lower(x, w).compile()
+
+
+def test_looplm_prefill_compiles_for_v5e(one_chip):
+    """One Ouro-2.6B layer at its published widths, run twice with
+    shared weights over a 4096-token sequence: the whole-net executable
+    that returns the logits alone compiles for one chip, is fed one
+    step's weights, and keeps the attention, norm and fc layers' scopes
+    (XLA fuses every glu into the down matmul)."""
+    hw = eyeriss_multinode()
+    graph = get_net("looplm", batch=1, seq=4096, layers=1, steps=2)
+    nplan = lower_network(solve(graph, hw), graph, hw)
+    assert not nplan.invalid_layers()
+    fused = FusedNetwork(nplan)
+    specs = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip)
+             for k, v in input_specs(nplan).items()}
+    weights = {k: v for k, v in specs.items() if k.endswith(".W")}
+    acts = {k: v for k, v in specs.items() if not k.endswith(".W")}
+    assert len(weights) == fused.weight_arrays == 10
+    compiled = fused._fn(("net", "outputs", False)).lower(
+        acts, weights).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == 49152 * 4
+    assert 0 < mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < V5E_HBM_BYTES
+    from repro.lower.fuse import hlo_op_layers
+    kinds = {nplan.plans[n].kind
+             for n in hlo_op_layers(compiled.as_text(), nplan.order).values()}
+    assert {"attention", "norm", "fc"} <= kinds
