@@ -77,6 +77,10 @@ _m_tied = metrics.gauge(
     "fused_tied_layers",
     "layers of the last-built fused executable that read another "
     "layer's weights")
+_m_pairs = metrics.gauge(
+    "fused_attention_pair_share",
+    "score pairs the causal attention layers of the last-built fused "
+    "executable form, over the pairs of their whole score matrices")
 
 
 # ---------------------------------------------------------------------------
@@ -147,24 +151,82 @@ def _eltwise(plan: KernelPlan, xs) -> jnp.ndarray:
     return acc
 
 
+#: fewest query rows a block of a split causal attention layer takes
+#: (``causal_blocks``)
+CAUSAL_QUERY_BLOCK = 512
+#: most blocks a causal attention layer is split into.  Each block's
+#: products and softmax compile to fusions of their own: one layer at
+#: 4096 tokens compiles for a v5e to 2.0 MB of executable unsplit, 6.6 MB
+#: in 4 blocks and 12.1 MB in 8, and a net's whole executable has to stay
+#: small enough for JAX's persistent compilation cache to keep it
+CAUSAL_MAX_BLOCKS = 4
+
+
+def causal_blocks(sq: int, skv: int) -> List[Tuple[int, int, int]]:
+    """``[(q0, q1, kend)]``: the query blocks of a causal attention layer
+    of ``sq`` queries over ``skv`` keys, where query ``i`` sees the keys
+    up to ``i + skv - sq``.  Block ``[q0, q1)`` forms scores against
+    keys ``[0, kend)``, the prefix its last row sees, and no others.
+    Blocks are ``CAUSAL_QUERY_BLOCK`` rows or more, a multiple of the
+    MXU's 128-row tile, and at most ``CAUSAL_MAX_BLOCKS`` of them.  A
+    layer shorter than two blocks, or with fewer keys than queries, is
+    one block over every key."""
+    bq = max(CAUSAL_QUERY_BLOCK,
+             128 * -(-sq // (128 * CAUSAL_MAX_BLOCKS)))
+    if sq < 2 * CAUSAL_QUERY_BLOCK or skv < sq:
+        return [(0, sq, skv)]
+    return [(q0, min(q0 + bq, sq), min(q0 + bq, sq) + skv - sq)
+            for q0 in range(0, sq, bq)]
+
+
+def attention_pair_share(layers) -> float:
+    """The score pairs (query x key) the causal attention layers among
+    ``layers`` form under ``causal_blocks``, over the pairs of their
+    whole score matrices; 1.0 when there is none."""
+    formed = whole = 0
+    for layer in layers:
+        if layer.kind != "attention" or not layer.meta.get("causal"):
+            continue
+        sq, skv, n = layer.dim("X"), layer.dim("C"), layer.dim("N")
+        formed += n * sum((q1 - q0) * kend
+                          for q0, q1, kend in causal_blocks(sq, skv))
+        whole += n * sq * skv
+    return formed / whole if whole else 1.0
+
+
+def _attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+            offset: Optional[int]) -> jnp.ndarray:
+    """softmax(q k^T / sqrt(D)) v over [N, S, D]; with an ``offset``,
+    key ``j`` is masked from query row ``i`` unless ``j <= i + offset``."""
+    s = jnp.einsum("nqd,nkd->nqk", q, k, precision=MATMUL_PRECISION,
+                   preferred_element_type=jnp.float32) * q.shape[-1] ** -0.5
+    if offset is not None:
+        qpos = jax.lax.broadcasted_iota(jnp.int32, s.shape[1:], 0)
+        kpos = jax.lax.broadcasted_iota(jnp.int32, s.shape[1:], 1)
+        s = jnp.where(kpos <= qpos + offset, s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("nqk,nkd->nqd", p, v, precision=MATMUL_PRECISION,
+                      preferred_element_type=jnp.float32)
+
+
 def _attention(plan: KernelPlan, q: jnp.ndarray, k: jnp.ndarray,
                v: jnp.ndarray) -> jnp.ndarray:
     """softmax(q k^T / sqrt(D)) v over [N, S, D], with q and k rotated by
     RoPE and key positions after the query's masked, as the layer's meta
-    says.  The whole score matrix is formed (no causal skipping)."""
+    says.  A causal layer runs its queries in the blocks of
+    ``causal_blocks``: each block forms scores against its key prefix
+    alone, never the keys past its last row, which the mask would zero.
+    Every row still holds all of its keys, so each block's softmax is
+    the exact row softmax; the blocks' outputs are concatenated along
+    the query axis."""
     layer = plan.layer
     q, k = attention_inputs(layer, q, k)
-    s = jnp.einsum("nqd,nkd->nqk", q, k, precision=MATMUL_PRECISION,
-                   preferred_element_type=jnp.float32) \
-        * layer.dim("K") ** -0.5
-    if layer.meta.get("causal"):
-        sq, skv = s.shape[1], s.shape[2]
-        qpos = jax.lax.broadcasted_iota(jnp.int32, (sq, skv), 0)
-        kpos = jax.lax.broadcasted_iota(jnp.int32, (sq, skv), 1)
-        s = jnp.where(kpos <= qpos + (skv - sq), s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("nqk,nkd->nqd", p, v, precision=MATMUL_PRECISION,
-                      preferred_element_type=jnp.float32)
+    sq, skv = q.shape[1], k.shape[1]
+    if not layer.meta.get("causal"):
+        return _attend(q, k, v, None)
+    return jnp.concatenate(
+        [_attend(q[:, q0:q1], k[:, :kend], v[:, :kend], q0 + skv - sq)
+         for q0, q1, kend in causal_blocks(sq, skv)], axis=1)
 
 
 def _norm(plan: KernelPlan, x: jnp.ndarray, g: jnp.ndarray) -> jnp.ndarray:
@@ -363,8 +425,9 @@ class FusedNetwork:
     call, until the jitted call returns.
 
     Building one sets the gauges ``fused_weight_arrays`` (the ``.W``
-    arrays a call is fed: one per weight owner) and ``fused_tied_layers``
-    (the layers that read another layer's weights).
+    arrays a call is fed: one per weight owner), ``fused_tied_layers``
+    (the layers that read another layer's weights) and
+    ``fused_attention_pair_share`` (``attention_pair_share``).
     """
 
     def __init__(self, nplan: NetworkPlan):
@@ -382,8 +445,10 @@ class FusedNetwork:
                                   if l.kind in WEIGHT_KINDS})
         self.tied_layers = sum(l.weight_owner != l.name for l in layers
                                if l.kind in WEIGHT_KINDS)
+        self.attention_pair_share = attention_pair_share(layers)
         _m_weights.set(self.weight_arrays)
         _m_tied.set(self.tied_layers)
+        _m_pairs.set(self.attention_pair_share)
 
     # -- builders -----------------------------------------------------------
 
@@ -553,5 +618,6 @@ def clear_cache() -> None:
 
 
 __all__ = ["FusedNetwork", "fused_runner", "plan_signature", "input_specs",
-           "compiled_plan_fn", "hlo_op_layers", "cache_stats",
+           "compiled_plan_fn", "causal_blocks", "attention_pair_share",
+           "hlo_op_layers", "cache_stats",
            "clear_cache", "resolve_backend"]

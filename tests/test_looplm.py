@@ -4,6 +4,8 @@ the interpret per-layer runner, every layer held to the model-level
 oracle ``kernels.ref.looplm_ref``; weights shared across loop steps; the
 causal mask; the solver's resident-dim rule at full width; the layer
 scopes of the new kinds."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,7 +18,10 @@ from repro.hw.presets import PRESETS
 from repro.kernels import ref
 from repro.lower import lower_network, make_network_inputs, network_runner
 from repro.lower.exec import ORACLE_TOL, rel_error
-from repro.lower.fuse import compiled_plan_fn, fused_runner
+from repro.lower.fuse import (CAUSAL_MAX_BLOCKS, CAUSAL_QUERY_BLOCK,
+                              FusedNetwork,
+                              attention_pair_share, causal_blocks,
+                              compiled_plan_fn, fused_runner, input_specs)
 from repro.lower.plan import lower_scheme
 from repro.obs import metrics
 from repro.workloads.layers import LayerGraph, attention, fc, rmsnorm
@@ -177,6 +182,140 @@ def test_compiled_attention_plan_is_causal_with_rope():
     full = ref.attention_ref(q[:, None], k[:, None], v[:, None],
                              causal=False)[:, 0]
     assert rel_error(fn(q, k, v), full) > 1e-2
+
+
+def _full_mask(q, k, v, causal):
+    """The single-einsum formulation over the whole score matrix, the
+    mask applied to all of it."""
+    sq, skv = q.shape[1], k.shape[1]
+    s = jnp.einsum("nqd,nkd->nqk", q, k,
+                   precision=jax.lax.Precision.HIGHEST) * q.shape[-1] ** -0.5
+    if causal:
+        keep = np.arange(skv)[None] <= np.arange(sq)[:, None] + (skv - sq)
+        s = jnp.where(keep, s, -1e30)
+    return jnp.einsum("nqk,nkd->nqd", jax.nn.softmax(s, axis=-1), v,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+BLOCKED_CASES = {
+    "aligned": dict(sq=1024),
+    "ragged": dict(sq=1100),
+    "key_offset": dict(sq=1024, skv=1280),
+    "grouped_kv": dict(sq=1024, heads=4, kv_heads=2),
+    "rope": dict(sq=1024, rope_theta=1e4),
+    "non_causal": dict(sq=1024, causal=False),
+}
+
+
+@pytest.mark.parametrize("case", list(BLOCKED_CASES))
+def test_blocked_attention_matches_ref_and_full_mask(case):
+    """A causal layer of two query blocks or more runs in the blocks of
+    ``causal_blocks``; it matches the oracle and the whole-matrix
+    formulation.  A non-causal layer takes the single-einsum path."""
+    c = {"skv": None, "heads": 2, "kv_heads": None, "rope_theta": 0.0,
+         "causal": True, **BLOCKED_CASES[case]}
+    sq, skv, h, d = c["sq"], c["skv"] or c["sq"], c["heads"], 32
+    kv = c["kv_heads"] or h
+    hw = PRESETS["eyeriss_multinode"]()
+    layer = attention("t.attn", 1, h, sq, d, seq_kv=c["skv"],
+                      causal=c["causal"], rope_theta=c["rope_theta"],
+                      kv_heads=c["kv_heads"])
+    plan = lower_scheme(solve_intra_layer(layer, hw)[0], hw)
+    assert plan.valid, plan.reason
+    assert len(causal_blocks(sq, skv)) > 1    # long enough to split
+    kq, kk, kvv = jax.random.split(jax.random.PRNGKey(7), 3)
+    q = jax.random.normal(kq, (h, sq, d), jnp.float32)
+    k, v = (jax.random.normal(key, (kv, skv, d), jnp.float32)
+            for key in (kk, kvv))
+    qr, kr = q, k                        # the layer rotates q and k itself
+    if c["rope_theta"]:
+        qr, kr = (ref.rope_ref(a[:, None], c["rope_theta"])[:, 0]
+                  for a in (q, k))
+    want = ref.attention_ref(qr[None], kr[None], v[None],
+                             causal=c["causal"])[0]
+
+    def rep(a):                          # as split_qkv repeats K/V heads
+        return jnp.repeat(a, h // kv, axis=0)
+
+    fn = compiled_plan_fn(plan)[0]
+    text = jax.jit(fn).lower(q, rep(k), rep(v)).as_text(dialect="hlo")
+    assert (f"f32[{h},{sq},{skv}]" in text) != c["causal"]
+    if c["causal"]:                      # each block's scores, no more
+        assert all(f"f32[{h},{q1 - q0},{kend}]" in text
+                   for q0, q1, kend in causal_blocks(sq, skv))
+    got = fn(q, rep(k), rep(v))
+    assert rel_error(got, want) < ORACLE_TOL
+    assert rel_error(got, _full_mask(qr, rep(kr), rep(v), c["causal"])) \
+        < 1e-6
+
+
+@pytest.mark.parametrize("sq,skv", [(16, 16), (1023, 1023), (1024, 1024),
+                                    (1100, 1100), (1024, 1280),
+                                    (2100, 2100), (4096, 4096),
+                                    (8192, 8192), (1536, 1024)])
+def test_causal_blocks_cover_each_row_once_within_its_keys(sq, skv):
+    blocks = causal_blocks(sq, skv)
+    rows = [i for q0, q1, _ in blocks for i in range(q0, q1)]
+    assert rows == list(range(sq))
+    for q0, q1, kend in blocks:
+        # the block's last row sees every key below kend; no row of the
+        # block sees a key past it
+        assert kend == min(skv, q1 + skv - sq)
+    if sq < 2 * CAUSAL_QUERY_BLOCK or skv < sq:
+        assert blocks == [(0, sq, skv)]
+        return
+    sizes = {q1 - q0 for q0, q1, _ in blocks[:-1]}
+    assert len(sizes) == 1 and blocks[-1][1] - blocks[-1][0] <= min(sizes)
+    assert min(sizes) % 128 == 0 and min(sizes) >= CAUSAL_QUERY_BLOCK
+    assert 2 <= len(blocks) <= CAUSAL_MAX_BLOCKS
+
+
+def _s4096_probe(causal):
+    hw = PRESETS["eyeriss_multinode"]()
+    graph = LayerGraph("probe", [
+        fc("qkv", 4096, 2048, 6144),
+        attention("attn", 1, 16, 4096, 128, src=["qkv"], causal=causal,
+                  rope_theta=1e6)])
+    nplan = lower_network(solve(graph, hw), graph, hw)
+    assert nplan.executable, nplan.invalid_layers()
+    return FusedNetwork(nplan)
+
+
+def _pair_gauge():
+    snap = metrics.REGISTRY.snapshot()
+    return snap["fused_attention_pair_share"]["series"][0]["value"]
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_s4096_attention_forms_its_causal_prefix_alone(causal):
+    """Ouro-2.6B's ``attention(1, 16, 4096, 128)``: causal, 4 query blocks
+    of 1024 rows form 10 of the 16 block pairs and no [16, 4096, 4096]
+    score matrix is traced; non-causal, the whole matrix.  Lowered
+    only."""
+    fused = _s4096_probe(causal)
+    share = 10 / 16 if causal else 1.0
+    assert fused.attention_pair_share == pytest.approx(share)
+    assert _pair_gauge() == pytest.approx(share)
+    specs = input_specs(fused.nplan)
+    acts = {k: v for k, v in specs.items() if not k.endswith(".W")}
+    weights = {k: v for k, v in specs.items() if k.endswith(".W")}
+    text = fused._fn(("net", "outputs", False)).lower(
+        acts, weights).as_text(dialect="hlo")
+    assert ("f32[16,4096,4096]" in text) != causal
+    widths = {int(w) for w in re.findall(r"f32\[16,1024,(\d+)\]", text)
+              if int(w) >= 1024}         # score blocks, not row sums or PV
+    assert widths == ({1024, 2048, 3072, 4096} if causal else set())
+
+
+def test_pair_share_reads_one_without_a_split_causal_layer(tiny):
+    nplan, _ = tiny
+    layers = [nplan.plans[n].layer for n in nplan.order]
+    assert any(l.kind == "attention" and l.meta.get("causal")
+               for l in layers)
+    assert attention_pair_share(layers) == 1.0
+    assert fused_runner(nplan, cache=False).attention_pair_share == 1.0
+    assert _pair_gauge() == 1.0
+    assert attention_pair_share([attention("a", 1, 16, 4096, 128)]) == 1.0
 
 
 @pytest.mark.parametrize("preset", PRESET_NAMES)
