@@ -223,8 +223,8 @@ def _bench_fused(quick: bool) -> dict:
         sched = solve(net, hw)
         nplan = lower_network(sched, net, hw)
         inputs = make_network_inputs(nplan, 0)
-        run_i = network_runner(nplan, inputs, jit=True, backend="interpret")
-        run_c = network_runner(nplan, inputs, jit=True, backend="compiled",
+        run_i = network_runner(nplan, inputs, backend="interpret")
+        run_c = network_runner(nplan, inputs, backend="compiled",
                                keep="boundary")
 
         def best(run):

@@ -1,37 +1,35 @@
-"""Backend selection — the single source of truth for interpret-vs-compiled.
+"""Backend selection: the one switch between the lowering tier's
+executors, and the kernel-impl default of ``kernels.ops``.
 
-Two tiers of the stack used to carry their own ad-hoc flags: the kernel
-API (``kernels.ops``) dispatched on an ``impl`` string with a private
-``_on_tpu()`` probe, and the lowering tier (``lower.exec`` /
-``lower.netexec``) threaded a bare ``interpret: bool``.  Both now resolve
-through this module, so "what actually runs" is decided in exactly one
-place:
+execution-backend tier (``lower.exec`` / ``lower.netexec`` /
+``lower.meshexec`` / ``lower.fuse``): every entry point takes a
+``backend`` name, checked by ``resolve_backend``.
+
+    =============  ========================================================
+    ``interpret``  per-layer ``pl.pallas_call(interpret=True)``
+                   (``exec.pallas_kernels``) — the numerics **oracle**
+                   backend; runs everywhere, slowly.
+    ``pallas``     the same per-layer Pallas kernels, compiled for the
+                   TPU; plans whose blocks break the TPU tiling are
+                   refused before compiling (``exec._check_tpu_tiling``).
+    ``compiled``   fused XLA (``fuse.compiled_kernels``): every layer of
+                   a segment, or of the whole net, traced into **one**
+                   jitted executable — the default measured path, and the
+                   one the chip benchmark runs.
+    =============  ========================================================
+
+All three run the same graph walk (``netexec.layer_output``) on their
+own map from layer kind to kernel, and are held to the ``kernels/ref.py``
+oracles (``exec.ORACLE_KERNELS``).
 
 kernel-impl tier (``kernels.ops``: attention / ssd wrappers)
     ``resolve_impl("auto")`` -> ``"pallas"`` on TPU, ``"jnp"`` elsewhere.
-
-execution-backend tier (``lower.exec`` / ``lower.netexec`` / ``lower.fuse``)
-    =============  ========================================================
-    ``interpret``  per-layer ``pl.pallas_call(interpret=True)`` — the
-                   bit-accuracy **oracle**; runs everywhere, slowly.
-    ``pallas``     per-layer compiled ``pl.pallas_call`` for the TPU;
-                   plans whose blocks break the TPU tiling are refused
-                   before compiling (``exec._check_tpu_tiling``).
-    ``compiled``   fused XLA segments (``lower.fuse``): every kernel of a
-                   chain segment traced into **one** jitted executable —
-                   the default measured path, and the one that runs on
-                   the chip (``chip_smoke.py``).
-    =============  ========================================================
-
-``resolve_backend`` also accepts the legacy ``interpret`` bool so existing
-call sites keep their meaning: ``interpret=True`` -> ``"interpret"``,
-``interpret=False`` -> ``"pallas"``.
 """
 from __future__ import annotations
 
 import os
 from pathlib import Path
-from typing import Dict, Optional
+from typing import Dict
 
 import jax
 
@@ -87,22 +85,12 @@ def resolve_impl(impl: str = "auto") -> str:
     return default_impl() if impl == "auto" else impl
 
 
-def resolve_backend(backend: Optional[str] = None,
-                    interpret: Optional[bool] = None) -> str:
-    """Resolve an execution backend name for the lowering tier.
-
-    ``backend`` wins when given; otherwise the legacy ``interpret`` bool
-    maps to its historical meaning; with neither, the default measured
-    path (``compiled``) is chosen.
-    """
-    if backend is not None:
-        if backend not in BACKENDS:
-            raise ValueError(f"unknown backend {backend!r}; expected one "
-                             f"of {BACKENDS}")
-        return backend
-    if interpret is not None:
-        return "interpret" if interpret else "pallas"
-    return DEFAULT_BACKEND
+def resolve_backend(backend: str) -> str:
+    """``backend``, checked to be one of ``BACKENDS``."""
+    if backend not in BACKENDS:
+        raise ValueError(f"unknown backend {backend!r}; expected one "
+                         f"of {BACKENDS}")
+    return backend
 
 
 def backend_interprets(backend: str) -> bool:

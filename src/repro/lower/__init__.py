@@ -1,7 +1,12 @@
 """Lowering subsystem: compile solved dataflow schemes into executable
-Pallas plans, execute/verify them, and calibrate the cost model against
-measured runtimes — at two tiers:
+plans, run them on one of three backends, verify them against the
+``kernels/ref.py`` oracles, and calibrate the cost model against
+measured runtimes.
 
+  kind table
+      kinds.KINDS                  one record per executable layer kind:
+         input shape, lone-plan operands, weight initialiser, how the
+         graph walk feeds it
   layer tier
       solver (LayerScheme)
           -> plan.lower_scheme                      (KernelPlan)
@@ -11,15 +16,23 @@ measured runtimes — at two tiers:
           -> netplan.lower_network                  (NetworkPlan: ordered
              kernel plans + segment buffer schedule w/ on-chip forwarding)
           -> netexec.execute_network / verify_network / measure_network
-  compiled tier
-      fuse.fused_runner                  (FusedNetwork: whole segments /
-         whole net jitted as single executables, process-wide cache
-         keyed by fuse.plan_signature — the default measured backend;
-         the interpret tier above stays the bit-accuracy oracle)
+
+Every executor runs one graph walk (``netexec.layer_output``) on a map
+from kind to kernel, one map per tier: ``exec.pallas_kernels`` (the
+``interpret`` oracle backend and ``pallas``), ``fuse.compiled_kernels``
+(``compiled``: ``fuse.fused_runner`` traces whole segments or the whole
+net into one executable, cached process-wide by ``fuse.plan_signature``
+— the default measured backend) and ``exec.ORACLE_KERNELS`` (the
+reference).  ``kernels.backend`` names the backends.
+
   calibration
       calibrate.run_calibration          (per-kernel Spearman + fit,
          per-backend coefficients)
       calibrate.run_network_calibration  (end-to-end network Spearman)
+
+Adding a layer kind takes one ``kinds.KINDS`` entry, one kernel in each
+tier's map, and the kind's cost terms (its ``workloads.layers``
+constructor).
 """
 from .plan import GridAxis, KernelPlan, lower_scheme, lower_schedule
 from .exec import (execute_plan, make_inputs, measure_plan,

@@ -35,6 +35,7 @@ from ..core.directives import LayerScheme, canonical_orders
 from ..core.solver.intralayer import Constraints, solve_intra_layer
 from ..hw.template import HWTemplate
 from ..hw.presets import eyeriss_multinode
+from ..kernels.backend import BACKENDS, ORACLE_BACKEND, resolve_backend
 from ..workloads.layers import LayerSpec, attention, conv, fc
 from .exec import (ORACLE_TOL, make_inputs, plan_runner, reference_output,
                    rel_error)
@@ -161,17 +162,16 @@ def fit_calibration(pairs: List[Dict], hw: HWTemplate,
 
 def run_calibration(hw: Optional[HWTemplate] = None, quick: bool = True,
                     layers: Optional[Sequence[LayerSpec]] = None,
-                    n_variants: int = 3, interpret: bool = True,
-                    verify: bool = True, iters: int = 2,
-                    seed: int = 0, backend: Optional[str] = None) -> Dict:
+                    n_variants: int = 3, verify: bool = True,
+                    iters: int = 2, seed: int = 0,
+                    backend: str = ORACLE_BACKEND) -> Dict:
     """Full calibration sweep; returns a JSON-safe record (see module
     docstring).  ``record["calibration"]`` round-trips through
     ``cost_model.Calibration.from_json_dict`` and carries the executed
     ``backend``, so ``load_calibration`` installs it per backend —
     compiled-backend coefficients never price interpreter runs."""
-    from ..kernels.backend import resolve_backend
     from .netexec import record_latency_drift
-    backend = resolve_backend(backend, interpret)
+    backend = resolve_backend(backend)
     hw = hw if hw is not None else default_hw()
     layers = list(layers) if layers is not None else default_sweep(quick)
     pairs: List[Dict] = []
@@ -256,9 +256,9 @@ def default_network_sweep(quick: bool = True):
 
 def run_network_calibration(hw: Optional[HWTemplate] = None,
                             quick: bool = True, nets=None,
-                            interpret: bool = True, iters: int = 2,
-                            seed: int = 0, tol: float = ORACLE_TOL,
-                            backend: Optional[str] = None) -> Dict:
+                            iters: int = 2, seed: int = 0,
+                            tol: float = ORACLE_TOL,
+                            backend: str = ORACLE_BACKEND) -> Dict:
     """End-to-end network calibration: each net is solved, lowered to a
     ``NetworkPlan``, verified against the whole-graph reference pass, and
     its measured wall clock compared with the schedule's predicted
@@ -266,12 +266,11 @@ def run_network_calibration(hw: Optional[HWTemplate] = None,
     (does the solver order whole nets the way execution does?), the
     counterpart of the per-kernel gate in ``run_calibration``."""
     from ..core.solver import solve
-    from ..kernels.backend import resolve_backend
     from .netexec import (compare_network, make_network_inputs,
                           measure_network, network_runner)
     from .netplan import lower_network
 
-    backend = resolve_backend(backend, interpret)
+    backend = resolve_backend(backend)
     hw = hw if hw is not None else default_hw()
     nets = list(nets) if nets is not None else default_network_sweep(quick)
     entries: List[Dict] = []
@@ -290,7 +289,7 @@ def run_network_calibration(hw: Optional[HWTemplate] = None,
             continue
         # one compiled runner serves verification, warmup and timing
         inputs = make_network_inputs(nplan, seed)
-        run = network_runner(nplan, inputs, jit=True, backend=backend)
+        run = network_runner(nplan, inputs, backend=backend)
         ver = compare_network(nplan, run(), inputs, tol)
         entry = {
             "net": net.name,
@@ -345,20 +344,18 @@ def load_record(path: str) -> Dict:
 
 
 def main(argv=None) -> int:
-    """CLI sweep driver: ``python -m repro.lower.calibrate [--compiled]``.
+    """CLI sweep driver: ``python -m repro.lower.calibrate [--backend B]``.
 
-    ``--compiled`` measures the fused XLA tier instead of the interpret
-    oracle; the emitted record (and its fitted coefficients) carry the
-    backend, so loading it calibrates ``predicted_seconds`` for that
-    backend only."""
+    ``--backend compiled`` measures the fused XLA tier instead of the
+    interpret oracle; the emitted record (and its fitted coefficients)
+    carry the backend, so loading it calibrates ``predicted_seconds``
+    for that backend only."""
     import argparse
     parser = argparse.ArgumentParser(description=__doc__)
-    parser.add_argument("--compiled", action="store_true",
-                        help="measure the fused compiled backend instead "
-                             "of the interpret oracle")
-    parser.add_argument("--backend", default=None,
-                        choices=["interpret", "pallas", "compiled"],
-                        help="explicit backend (overrides --compiled)")
+    parser.add_argument("--backend", default=ORACLE_BACKEND,
+                        choices=BACKENDS,
+                        help="the backend measured (default: the "
+                             "interpret oracle)")
     parser.add_argument("--network", action="store_true",
                         help="run the end-to-end network sweep instead of "
                              "the per-kernel sweep")
@@ -368,13 +365,13 @@ def main(argv=None) -> int:
     parser.add_argument("--out", default=None,
                         help="write the JSON record here")
     args = parser.parse_args(argv)
-    backend = args.backend or ("compiled" if args.compiled else "interpret")
     if args.network:
         record = run_network_calibration(quick=not args.full,
-                                         iters=args.iters, backend=backend)
+                                         iters=args.iters,
+                                         backend=args.backend)
     else:
         record = run_calibration(quick=not args.full, iters=args.iters,
-                                 backend=backend)
+                                 backend=args.backend)
     if args.out:
         save_record(record, args.out)
     print(json.dumps({k: v for k, v in record.items()

@@ -1,19 +1,27 @@
-"""Execute ``KernelPlan``s through ``pl.pallas_call``.
+"""The Pallas tier and the oracle of one ``KernelPlan``.
 
-One generic Pallas kernel per supported layer family (matmul/fc, conv,
+One generic Pallas kernel per layer kind (``_run_*``: matmul/fc, conv,
 attention, pool, eltwise, RMSNorm, SwiGLU product), parameterized
-entirely by the plan: the grid is the solver's
-DRAM-level loop nest (same order), the BlockSpecs carry the plan's block
-sizes and index maps, and reduction grid axes accumulate into the output
-block across revisits (initialized on the first visit, exactly like the
-directive model's partial-sum residency).
+entirely by the plan: the grid is the solver's DRAM-level loop nest
+(same order), the BlockSpecs carry the plan's block sizes and index
+maps, and reduction grid axes accumulate into the output block across
+revisits (initialized on the first visit, exactly like the directive
+model's partial-sum residency).
 
-Runs in interpret mode (the numerics/calibration gate).  The compiled
-``pallas`` backend refuses, before compiling, any plan whose blocks break
-the TPU's (8, 128) tiling rule (``_check_tpu_tiling``) — which today is
-every plan of an Eyeriss-sized template, whose blocks are cut for a small
-global buffer.  Outputs are verified against the pure-jnp oracles in
-``kernels/ref.py``.
+Each tier is a map from kind to kernel, every kernel called
+``kernel(plan, *operands)`` (``lower.kinds``): ``pallas_kernels`` binds
+the Pallas kernels' ``interpret`` flag from the backend,
+``ORACLE_KERNELS`` holds the ``kernels/ref.py`` oracles, and
+``fuse.compiled_kernels`` the compiled tier.  ``plan_runner`` runs one
+plan on the kernel of its backend; ``make_inputs`` draws the plan's
+operands from the kind table; ``reference_output`` is the oracle's
+answer every backend is held to (``ORACLE_TOL``).
+
+The ``interpret`` backend is the numerics oracle.  The ``pallas``
+backend refuses, before compiling, any plan whose blocks break the TPU's
+(8, 128) tiling rule (``_check_tpu_tiling``), which today is every plan
+of an Eyeriss-sized template, whose blocks are cut for a small global
+buffer.
 
 Notes on fidelity:
   * everything on-chip (all node GBUFs + the PE arrays below them) is one
@@ -32,7 +40,7 @@ from __future__ import annotations
 
 import functools
 import time
-from typing import Dict, Optional, Sequence, Tuple
+from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -40,7 +48,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from ..kernels import ref
-from ..kernels.backend import backend_interprets, resolve_backend
+from ..kernels.backend import (ORACLE_BACKEND, backend_interprets,
+                               resolve_backend)
+from .kinds import input_extent, kind_of, operand_names
 from .plan import KernelPlan
 
 
@@ -465,119 +475,83 @@ def _run_glu(plan: KernelPlan, x: jnp.ndarray,
 
 
 # ---------------------------------------------------------------------------
-# Public API: inputs, execution, verification, measurement
+# Public API: kernel maps, inputs, execution, verification, measurement
 # ---------------------------------------------------------------------------
 
-def input_extent(layer) -> Tuple[int, int]:
-    """Minimal halo'd spatial input extent of a conv/pool layer under
-    VALID padding: (X-1)*stride + R — the single definition shared by the
-    layer-tier inputs and the network tier's shape plumbing."""
-    R, S = int(layer.meta["R"]), int(layer.meta["S"])
-    stride = int(layer.meta["stride"])
-    return ((layer.dim("X") - 1) * stride + R,
-            (layer.dim("Y") - 1) * stride + S)
+def pallas_kernels(interpret: bool) -> Dict[str, Callable]:
+    """kind -> Pallas kernel ``kernel(plan, *operands)``, interpreted or
+    compiled as ``interpret`` says."""
+    bind = functools.partial
+    return {"conv": bind(_run_conv, interpret=interpret),
+            "fc": bind(_run_fc, interpret=interpret),
+            "attention": bind(_run_attention, interpret=interpret),
+            "pool": bind(_run_pool, interpret=interpret),
+            "eltwise": lambda plan, *xs: _run_eltwise(plan, xs, interpret),
+            "norm": bind(_run_norm, interpret=interpret),
+            "glu": bind(_run_glu, interpret=interpret)}
 
 
 def make_inputs(plan: KernelPlan, seed: int = 0) -> Dict[str, jnp.ndarray]:
-    """Deterministic dense float32 inputs matching the plan's canonical
-    layouts (fc: I[N,C] W[C,K]; conv: I[N,C,XI,YI] W[K,C,R,S];
-    attention: Q/K/V [N, S, D]; pool: I[N,C,XI,YI]; eltwise: A/B
-    [N,C,X,Y]; norm: I[N,C] W[C]; glu: I[N,2C], gate then up)."""
+    """Deterministic dense float32 inputs in the plan's canonical layouts
+    (``kinds.LayerKind.plan_operands``): the i-th activation operand is a
+    standard normal from the i-th key of ``seed``, and the weight, for a
+    kind that has one, comes from the next key through the kind's
+    initialiser."""
     layer = plan.layer
+    kind = kind_of(plan.kind)
     keys = jax.random.split(jax.random.PRNGKey(seed), 3)
-    if plan.kind == "fc":
-        return {"I": jax.random.normal(keys[0], (layer.dim("N"),
-                                                 layer.dim("C")), jnp.float32),
-                "W": jax.random.normal(keys[1], (layer.dim("C"),
-                                                 layer.dim("K")), jnp.float32)
-                * layer.dim("C") ** -0.5}
-    if plan.kind == "conv":
-        R, S = int(layer.meta["R"]), int(layer.meta["S"])
-        XI, YI = input_extent(layer)
-        fan_in = layer.dim("C") * R * S
-        return {"I": jax.random.normal(
-                    keys[0], (layer.dim("N"), layer.dim("C"), XI, YI),
-                    jnp.float32),
-                "W": jax.random.normal(
-                    keys[1], (layer.dim("K"), layer.dim("C"), R, S),
-                    jnp.float32) * fan_in ** -0.5}
-    if plan.kind == "attention":
-        NH, Sq, Skv, D = (layer.dim("N"), layer.dim("X"), layer.dim("C"),
-                          layer.dim("K"))
-        return {"Q": jax.random.normal(keys[0], (NH, Sq, D), jnp.float32),
-                "K": jax.random.normal(keys[1], (NH, Skv, D), jnp.float32),
-                "V": jax.random.normal(keys[2], (NH, Skv, D), jnp.float32)}
-    if plan.kind == "pool":
-        XI, YI = input_extent(layer)
-        return {"I": jax.random.normal(
-            keys[0], (layer.dim("N"), layer.dim("C"), XI, YI), jnp.float32)}
-    if plan.kind == "eltwise":
-        shape = tuple(layer.dim(d) for d in ("N", "C", "X", "Y"))
-        return {"A": jax.random.normal(keys[0], shape, jnp.float32),
-                "B": jax.random.normal(keys[1], shape, jnp.float32)}
-    if plan.kind == "norm":
-        N, C = layer.dim("N"), layer.dim("C")
-        return {"I": jax.random.normal(keys[0], (N, C), jnp.float32),
-                "W": 1.0 + 0.1 * jax.random.normal(keys[1], (C,),
-                                                   jnp.float32)}
-    if plan.kind == "glu":
-        return {"I": jax.random.normal(
-            keys[0], (layer.dim("N"), 2 * layer.dim("C")), jnp.float32)}
-    raise ValueError(f"unsupported kind {plan.kind!r}")
+    shapes = kind.plan_operands(layer)
+    inputs = {name: jax.random.normal(key, shape, jnp.float32)
+              for key, (name, shape) in zip(keys, shapes.items())}
+    if kind.weight is not None:
+        inputs["W"] = kind.weight(keys[len(shapes)], layer)
+    return inputs
 
 
-def plan_runner(plan: KernelPlan, interpret: bool = True,
-                jit: bool = False, backend: Optional[str] = None):
-    """Build a callable ``inputs_dict -> output`` for the plan.  With
-    ``jit=True`` the whole pallas_call is staged once and re-invocations
-    time the compiled executable (the measurement path).  ``backend``
-    resolves through ``kernels.backend`` (the one source of truth):
-    ``interpret``/``pallas`` run the Pallas kernel, ``compiled`` runs the
-    fused tier's XLA twin of the plan (``fuse.compiled_plan_fn``)."""
+def _kernels(backend: str) -> Dict[str, Callable]:
+    if backend == "compiled":
+        from .fuse import compiled_kernels     # lazy: fuse imports netexec
+        return compiled_kernels()
+    return pallas_kernels(backend_interprets(backend))
+
+
+def plan_fn(plan: KernelPlan, backend: str
+            ) -> Tuple[Callable, Tuple[str, ...]]:
+    """(fn, input names): ``fn(*inputs)`` runs the plan on its kind's
+    kernel under ``backend``, looked up when ``fn`` runs (or traces).
+    Refuses an invalid plan, and under ``pallas`` a plan the compiled
+    Pallas guards refuse, naming the layer."""
     if not plan.valid:
         raise ValueError(
             f"cannot execute invalid plan for layer {plan.layer.name!r}: "
             f"{plan.invalid_reason}")
-    backend = resolve_backend(backend, interpret)
-    if backend == "compiled":
-        from .fuse import compiled_plan_fn     # lazy: fuse imports netexec
-        base, names = compiled_plan_fn(plan)
-        fn = jax.jit(base) if jit else base
-        return lambda inputs: fn(*(inputs[n] for n in names))
-    interpret = backend_interprets(backend)
-    if not interpret:
+    if backend == "pallas":
         _check_compiled_pallas(plan)
-    if plan.kind == "fc":
-        names, base = ("I", "W"), \
-            lambda i, w: _run_fc(plan, i, w, interpret)
-    elif plan.kind == "conv":
-        names, base = ("I", "W"), \
-            lambda i, w: _run_conv(plan, i, w, interpret)
-    elif plan.kind == "attention":
-        names, base = ("Q", "K", "V"), \
-            lambda q, k, v: _run_attention(plan, q, k, v, interpret)
-    elif plan.kind == "pool":
-        names, base = ("I",), lambda i: _run_pool(plan, i, interpret)
-    elif plan.kind == "eltwise":
-        names, base = ("A", "B"), \
-            lambda a, b: _run_eltwise(plan, (a, b), interpret)
-    elif plan.kind == "norm":
-        names, base = ("I", "W"), \
-            lambda i, w: _run_norm(plan, i, w, interpret)
-    elif plan.kind == "glu":
-        names, base = ("I",), lambda i: _run_glu(plan, i, interpret)
-    else:
-        raise ValueError(f"unsupported kind {plan.kind!r}")
-    fn = jax.jit(base) if jit else base
+
+    def fn(*xs):
+        return _kernels(backend)[plan.kind](plan, *xs)
+    return fn, operand_names(plan.layer)
+
+
+def plan_runner(plan: KernelPlan, jit: bool = False,
+                backend: str = ORACLE_BACKEND):
+    """Build a callable ``inputs_dict -> output`` for the plan.  With
+    ``jit=True`` the kernel is staged once and re-invocations time the
+    compiled executable (the measurement path).  ``interpret`` and
+    ``pallas`` run the Pallas kernel, ``compiled`` the fused tier's XLA
+    kernel (``kernels.backend``)."""
+    fn, names = plan_fn(plan, resolve_backend(backend))
+    fn = jax.jit(fn) if jit else fn
     return lambda inputs: fn(*(inputs[n] for n in names))
 
 
 def execute_plan(plan: KernelPlan, inputs: Optional[Dict] = None,
-                 interpret: bool = True, seed: int = 0) -> jnp.ndarray:
-    """Run the plan through ``pl.pallas_call`` and return the output."""
-    run = plan_runner(plan, interpret)       # refuses invalid plans first,
+                 seed: int = 0,
+                 backend: str = ORACLE_BACKEND) -> jnp.ndarray:
+    """Run the plan on ``backend`` and return the output."""
+    run = plan_runner(plan, backend=backend)  # refuses invalid plans first,
     inputs = inputs if inputs is not None else make_inputs(plan, seed)
-    return run(inputs)                       # naming the layer + reason
+    return run(inputs)                        # naming the layer + reason
 
 
 def attention_reference(layer, q: jnp.ndarray, k: jnp.ndarray,
@@ -592,28 +566,27 @@ def attention_reference(layer, q: jnp.ndarray, k: jnp.ndarray,
                              causal=bool(layer.meta.get("causal", 0)))[:, 0]
 
 
+#: kind -> oracle ``kernel(plan, *operands)`` from ``kernels/ref.py``
+ORACLE_KERNELS: Dict[str, Callable] = {
+    "conv": lambda plan, x, w: ref.conv2d_ref(
+        x, w, stride=int(plan.layer.meta["stride"])),
+    "fc": lambda plan, x, w: ref.matmul_ref(x, w),
+    "attention": lambda plan, q, k, v: attention_reference(plan.layer, q,
+                                                           k, v),
+    "pool": lambda plan, x: ref.pool2d_ref(
+        x, int(plan.layer.meta["R"]), int(plan.layer.meta["S"]),
+        stride=int(plan.layer.meta["stride"])),
+    "eltwise": lambda plan, *xs: ref.eltwise_ref(*xs),
+    "norm": lambda plan, x, g: ref.rmsnorm_ref(x, g,
+                                               float(plan.layer.meta["eps"])),
+    "glu": lambda plan, x: ref.glu_ref(x),
+}
+
+
 def reference_output(plan: KernelPlan, inputs: Dict) -> jnp.ndarray:
     """Ground truth from ``kernels/ref.py`` for the plan's layer."""
-    if plan.kind == "fc":
-        return ref.matmul_ref(inputs["I"], inputs["W"])
-    if plan.kind == "conv":
-        return ref.conv2d_ref(inputs["I"], inputs["W"],
-                              stride=int(plan.layer.meta["stride"]))
-    if plan.kind == "attention":
-        return attention_reference(plan.layer, inputs["Q"], inputs["K"],
-                                   inputs["V"])
-    if plan.kind == "pool":
-        return ref.pool2d_ref(inputs["I"], int(plan.layer.meta["R"]),
-                              int(plan.layer.meta["S"]),
-                              stride=int(plan.layer.meta["stride"]))
-    if plan.kind == "eltwise":
-        return ref.eltwise_ref(inputs["A"], inputs["B"])
-    if plan.kind == "norm":
-        return ref.rmsnorm_ref(inputs["I"], inputs["W"],
-                               float(plan.layer.meta["eps"]))
-    if plan.kind == "glu":
-        return ref.glu_ref(inputs["I"])
-    raise ValueError(f"unsupported kind {plan.kind!r}")
+    names = operand_names(plan.layer)
+    return ORACLE_KERNELS[plan.kind](plan, *(inputs[n] for n in names))
 
 
 #: the one tolerance every backend is held to against the ``kernels/ref.py``
@@ -626,33 +599,33 @@ ORACLE_TOL = 1e-3
 
 
 def rel_error(out, want) -> float:
-    import numpy as np
     a = np.asarray(out, np.float32)
     b = np.asarray(want, np.float32)
     return float(np.max(np.abs(a - b)) / (np.abs(b).max() + 1e-9))
 
 
-def verify_plan(plan: KernelPlan, interpret: bool = True, seed: int = 0,
+def verify_plan(plan: KernelPlan, backend: str = ORACLE_BACKEND,
+                seed: int = 0,
                 tol: float = ORACLE_TOL) -> Tuple[bool, float]:
-    """Execute the plan and compare against the oracle.  Returns
-    (ok, max relative error)."""
+    """Execute the plan on ``backend`` and compare against the oracle.
+    Returns (ok, max relative error)."""
     inputs = make_inputs(plan, seed)
-    out = execute_plan(plan, inputs, interpret=interpret)
+    out = execute_plan(plan, inputs, backend=backend)
     err = rel_error(out, reference_output(plan, inputs))
     return err < tol, err
 
 
 def measure_plan(plan: KernelPlan, inputs: Optional[Dict] = None,
-                 interpret: bool = True, iters: int = 2,
-                 warmup: int = 1, jit: bool = True) -> float:
+                 backend: str = ORACLE_BACKEND, iters: int = 2,
+                 warmup: int = 1) -> float:
     """Measured wall-clock seconds for one plan execution (min over
     ``iters`` after ``warmup`` runs; ``block_until_ready`` fences).
 
-    Measures the jitted executable by default so the time reflects the
-    plan's actual compute/memory work, not per-call tracing overhead
+    Measures the jitted executable so the time reflects the plan's
+    actual compute/memory work, not per-call tracing overhead
     (compilation happens during warmup)."""
     inputs = inputs if inputs is not None else make_inputs(plan)
-    run = plan_runner(plan, interpret, jit=jit)
+    run = plan_runner(plan, jit=True, backend=backend)
     for _ in range(max(1, warmup)):
         jax.block_until_ready(run(inputs))
     best = float("inf")

@@ -1,18 +1,13 @@
-"""Fused compiled segment execution: whole segments as single executables.
+"""The compiled tier: a ``NetworkPlan``'s segments, or the whole net,
+traced into single jitted executables.
 
-The interpret tier (``netexec`` with ``backend="interpret"``) runs every
-layer as a separate interpret-mode ``pl.pallas_call`` with jax-array
-handoffs and host round-trips at segment boundaries — bit-accurate, and
-two to three orders of magnitude slower than the schedule it models
-(``BENCH_network.json``: mlp 0.40 s measured vs 0.0012 s predicted).
-This module is the compiled tier that kills that tax:
-
-  * **one jitted function per chain segment** — every kernel of the
-    segment, with the canonical shape adapter (``netexec.adapt_tensor``)
-    traced inline, inside a single ``jax.jit`` scope.  Forwarded tensors
-    (``LayerScheme.forward_bytes``, the PR-4 on-chip forwarding
-    machinery) are genuinely live values inside one executable, not jax
-    arrays round-tripping through Python dispatch;
+  * **one jitted function per chain segment** — the graph walk
+    (``netexec.layer_output``) over every layer of the segment, on the
+    compiled kernels (``compiled_kernels``), adapter traced inline,
+    inside a single ``jax.jit`` scope.  Forwarded tensors
+    (``LayerScheme.forward_bytes``, the on-chip forwarding machinery)
+    are live values inside one executable, not jax arrays round-tripping
+    through Python dispatch;
   * **a whole-``NetworkPlan`` jitted entry point** — the segment
     functions chained into one executable, external activations donatable
     (weights never donated: they are the resident state a serving node
@@ -23,20 +18,21 @@ This module is the compiled tier that kills that tax:
     same plan — autotune top-k re-ranking, ``SolveServer`` measured
     re-ranking, mesh task replay — pay tracing/compilation exactly once.
 
-Each layer's compiled kernel computes the layer's math in plain XLA,
-independent of the ``kernels/ref.py`` oracles it is verified against.
-A conv is one ``lax.conv_general_dilated`` at HIGHEST: XLA's windowed
+Each compiled kernel (``_fc``, ``_conv``, ``_pool``, ``_eltwise``,
+``_attention``, ``_norm``, ``_glu``) computes its layer's math in plain
+XLA, independent of the ``kernels/ref.py`` oracles it is verified
+against, and is looked up by name on this module each time a layer
+traces, so a kernel swapped on the module changes the executable.  A
+conv is one ``lax.conv_general_dilated`` at HIGHEST: XLA's windowed
 convolution folds the R/S taps into the contraction and writes the
 output once.  The pool keeps the R/S window as a slice + max loop.  The
-Pallas twins in ``exec.py`` keep the per-tap loop in-block, and the
-interpret tier built from them is the tap-loop implementation the tests
-hold against the oracles.
+Pallas twins in ``exec.py`` keep the per-tap loop in-block.
 
 What the compiled tier does *not* replay is the solver's DRAM-level
-grid walk: XLA owns the loop schedule inside a fused segment, which is
-exactly the point — the solver's inter-layer decisions (segmentation,
-forwarding) shape the executable, the intra-layer nest is the cost
-model's concern and stays measurable on the interpret oracle.
+grid walk: XLA owns the loop schedule inside a fused segment — the
+solver's inter-layer decisions (segmentation, forwarding) shape the
+executable, the intra-layer nest is the cost model's concern and stays
+measurable on the Pallas tier.
 """
 from __future__ import annotations
 
@@ -51,12 +47,10 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..kernels.backend import resolve_backend  # noqa: F401  (re-export)
 from ..obs import metrics, trace
-from .exec import attention_inputs
-from .netexec import (WEIGHT_KINDS, _check_executable, _eltwise_operands,
-                      adapt_tensor, layer_input, make_network_inputs,
-                      merge_heads, required_input_shape, split_qkv)
+from .exec import attention_inputs, plan_fn
+from .kinds import WEIGHT_KINDS
+from .netexec import _check_executable, layer_output, make_network_inputs
 from .netplan import NetworkPlan
 from .plan import KernelPlan
 
@@ -240,30 +234,21 @@ def _glu(plan: KernelPlan, x: jnp.ndarray) -> jnp.ndarray:
     return gate * jax.nn.sigmoid(gate) * up
 
 
+def compiled_kernels() -> Dict[str, Callable]:
+    """kind -> compiled kernel ``kernel(plan, *operands)``, read off the
+    module when called: the graph walk calls this as each layer traces,
+    so a kernel replaced on the module is the one traced."""
+    return {"conv": _conv, "fc": _fc, "attention": _attention,
+            "pool": _pool, "eltwise": lambda plan, *xs: _eltwise(plan, xs),
+            "norm": _norm, "glu": _glu}
+
+
 def compiled_plan_fn(plan: KernelPlan) -> Tuple[Callable, Tuple[str, ...]]:
     """(fn, input names) — the layer-tier compiled kernel for one plan,
     used by ``exec.plan_runner(backend="compiled")`` and the per-backend
     calibration sweep.  Unlike compiled Pallas, any DRAM loop order is
     executable (XLA owns the schedule), so no revisit-order guard."""
-    if not plan.valid:
-        raise ValueError(
-            f"cannot execute invalid plan for layer {plan.layer.name!r}: "
-            f"{plan.invalid_reason}")
-    if plan.kind == "fc":
-        return (lambda i, w: _fc(plan, i, w)), ("I", "W")
-    if plan.kind == "conv":
-        return (lambda i, w: _conv(plan, i, w)), ("I", "W")
-    if plan.kind == "pool":
-        return (lambda i: _pool(plan, i)), ("I",)
-    if plan.kind == "eltwise":
-        return (lambda a, b: _eltwise(plan, (a, b))), ("A", "B")
-    if plan.kind == "attention":
-        return (lambda q, k, v: _attention(plan, q, k, v)), ("Q", "K", "V")
-    if plan.kind == "norm":
-        return (lambda i, w: _norm(plan, i, w)), ("I", "W")
-    if plan.kind == "glu":
-        return (lambda i: _glu(plan, i)), ("I",)
-    raise ValueError(f"unsupported kind {plan.kind!r}")
+    return plan_fn(plan, "compiled")
 
 
 # ---------------------------------------------------------------------------
@@ -310,45 +295,21 @@ def input_specs(nplan: NetworkPlan) -> Dict[str, jax.ShapeDtypeStruct]:
 
 def _layer_out(nplan: NetworkPlan, name: str, vals: Dict,
                feed: Dict) -> jnp.ndarray:
-    """One layer's output during tracing: sources from already-computed
-    ``vals`` (in-graph), falling back to the external ``feed`` (the
-    ``.I`` inputs — and, at segment granularity, boundary tensors from
-    earlier segments), the canonical adapter inline — the traced mirror
-    of ``netexec._layer_fn``.  Everything it traces, adapter included,
-    sits under ``jax.named_scope(name)``: the layer's name reaches each
-    compiled instruction's ``op_name`` (``FusedNetwork.op_layers``)."""
+    """One layer's output during tracing: the graph walk over sources
+    from already-computed ``vals`` (in-graph), falling back to the
+    external ``feed`` (the ``.I`` inputs — and, at segment granularity,
+    boundary tensors from earlier segments).  Everything it traces,
+    adapter included, sits under ``jax.named_scope(name)``: the layer's
+    name reaches each compiled instruction's ``op_name``
+    (``FusedNetwork.op_layers``)."""
     plan = nplan.plans[name]
     layer = plan.layer
-    srcs = [s for s in layer.src if s in nplan.plans]
-
-    def src_val(s: str) -> jnp.ndarray:
-        return vals[s] if s in vals else feed[s]
-
+    srcs = [vals[s] if s in vals else feed[s]
+            for s in layer.src if s in nplan.plans]
     with jax.named_scope(name):
-        if plan.kind == "eltwise":
-            ops = _eltwise_operands(
-                [src_val(s) for s in srcs] if srcs else [feed[f"{name}.I"]],
-                layer)
-            return _eltwise(plan, ops)
-        if srcs:
-            x = layer_input(layer, src_val(srcs[0]))
-        else:
-            x = adapt_tensor(feed[f"{name}.I"], required_input_shape(layer))
-        w = feed.get(f"{layer.weight_owner}.W")
-        if plan.kind == "fc":
-            return _fc(plan, x, w)
-        if plan.kind == "conv":
-            return _conv(plan, x, w)
-        if plan.kind == "pool":
-            return _pool(plan, x)
-        if plan.kind == "norm":
-            return _norm(plan, x, w)
-        if plan.kind == "glu":
-            return _glu(plan, x)
-        if plan.kind == "attention":
-            return merge_heads(layer, _attention(plan, *split_qkv(layer, x)))
-    raise ValueError(f"cannot execute layer {name!r}: kind "
-                     f"{plan.kind!r} has no network-exec input feed")
+        return layer_output(plan, srcs, feed.get(f"{name}.I"),
+                            feed.get(f"{layer.weight_owner}.W"),
+                            compiled_kernels())
 
 
 _HLO_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.-]+)\s*=")
@@ -618,6 +579,6 @@ def clear_cache() -> None:
 
 
 __all__ = ["FusedNetwork", "fused_runner", "plan_signature", "input_specs",
-           "compiled_plan_fn", "causal_blocks", "attention_pair_share",
-           "hlo_op_layers", "cache_stats",
-           "clear_cache", "resolve_backend"]
+           "compiled_kernels", "compiled_plan_fn", "causal_blocks",
+           "attention_pair_share", "hlo_op_layers", "cache_stats",
+           "clear_cache"]

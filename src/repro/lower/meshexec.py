@@ -46,17 +46,16 @@ from concurrent.futures import Future, ThreadPoolExecutor
 from concurrent.futures import TimeoutError as FutureTimeout
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..core.solver.multinode import MultiNodePlan, repartition
-from ..kernels.backend import backend_interprets, resolve_backend
+from ..kernels.backend import ORACLE_BACKEND, resolve_backend
 from ..obs import metrics, trace
 from ..runtime import inject
 from ..runtime.fault import ElasticPlanner, NodeFailure
 from ..runtime.straggler import BackupDispatcher, StragglerDetector
-from .netexec import _check_executable, _layer_fn
+from .netexec import layer_steps
 from .netplan import NetworkPlan
 
 # -- telemetry (repro.obs) ---------------------------------------------------
@@ -86,9 +85,7 @@ class SegmentTask:
 
 
 def build_segment_tasks(nplan: NetworkPlan, weights: Dict,
-                        interpret: bool = True,
-                        jit: bool = True,
-                        backend: Optional[str] = None) -> List[SegmentTask]:
+                        backend: str = ORACLE_BACKEND) -> List[SegmentTask]:
     """Compile the plan's layers into per-segment tasks.
 
     ``weights`` holds the ``"<layer>.W"`` arrays (captured into the
@@ -97,16 +94,18 @@ def build_segment_tasks(nplan: NetworkPlan, weights: Dict,
     ``"<layer>.I"`` tensors through the state dict, so one compiled
     task list serves every request.
 
-    Under ``backend="compiled"`` each task wraps one fused segment
+    Under ``interpret`` and ``pallas`` each task runs its layers'
+    steps from ``netexec.layer_steps``, which holds the compiled Pallas
+    guards.  Under ``compiled`` each task wraps one fused segment
     executable from the process-wide cache (``fuse.fused_runner``):
     replaying a task after a node failure — or rebuilding the task list
     for the same plan on another request — reuses the traced
     executable.  A fused task only emits tensors some later segment or
-    the network output needs; tensors the interpret tier would
+    the network output needs; tensors the per-layer chain would
     round-trip but that stay inside one segment never leave the
     executable.
     """
-    backend = resolve_backend(backend, interpret)
+    backend = resolve_backend(backend)
     if backend == "compiled":
         from .fuse import fused_runner
         fused = fused_runner(nplan)
@@ -125,12 +124,7 @@ def build_segment_tasks(nplan: NetworkPlan, weights: Dict,
 
             tasks.append(SegmentTask(seg.index, acts, produces, run))
         return tasks
-    _check_executable(nplan)
-    interpret = backend_interprets(backend)
-    steps: Dict[str, Tuple[Callable, Tuple[str, ...]]] = {}
-    for name in nplan.order:
-        fn, srcs = _layer_fn(nplan, name, weights, interpret)
-        steps[name] = (jax.jit(fn) if jit else fn, srcs)
+    steps = layer_steps(nplan, weights, backend)
     # a forwarded tensor with a consumer outside its own segment must
     # still cross the boundary: emit it like a round-tripped tensor
     emit: Dict[str, bool] = {}
@@ -143,29 +137,19 @@ def build_segment_tasks(nplan: NetworkPlan, weights: Dict,
     tasks: List[SegmentTask] = []
     for seg in nplan.segments:
         names = tuple(seg.layer_names)
-        inseg = set(names)
-        consumes: List[str] = []
-        for n in names:
-            srcs = steps[n][1]
-            if srcs:
-                consumes += [s for s in srcs if s not in inseg]
-            else:
-                consumes.append(f"{n}.I")
+        consumes = [a for n in names for a in steps[n][1]
+                    if a not in names]
         produces = tuple(n for n in names if emit[n])
 
-        def run(state: Dict[str, np.ndarray], names=names,
-                inseg=inseg) -> Dict[str, np.ndarray]:
+        def run(state: Dict[str, np.ndarray],
+                names=names) -> Dict[str, np.ndarray]:
             onchip: Dict[str, jnp.ndarray] = {}
             out: Dict[str, np.ndarray] = {}
             for n in names:
-                fn, srcs = steps[n]
-                if srcs:
-                    args = [onchip[s] if s in onchip
-                            else jnp.asarray(state[s]) for s in srcs]
-                else:
-                    args = [jnp.asarray(state[f"{n}.I"])]
-                y = fn(*args)
-                if n in inseg and nplan.placements[n].forwarded:
+                fn, args = steps[n]
+                y = fn(*(onchip[a] if a in onchip
+                         else jnp.asarray(state[a]) for a in args))
+                if nplan.placements[n].forwarded:
                     onchip[n] = y
                 if emit[n]:
                     out[n] = np.asarray(y)

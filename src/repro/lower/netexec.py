@@ -1,21 +1,20 @@
-"""Execute a ``NetworkPlan`` end-to-end through ``pl.pallas_call``.
+"""Run a ``NetworkPlan`` end to end: the graph walk, the network's
+inputs, the per-layer Pallas chain and the whole-graph reference.
 
-The layer tier (``exec.py``) runs one kernel; this module chains every
-kernel of a lowered network in topological order, realizing the plan's
-buffer schedule:
-
-  * **forwarded** tensors (segment-internal, see ``netplan``) stay live
-    jax arrays handed directly from the producing kernel to its
-    consumers — never materialized through a host round-trip;
-  * **boundary** tensors are materialized to host numpy after the
-    producer and re-uploaded when consumed — the execution analogue of a
-    DRAM store + reload.
+**The graph walk** (``layer_output``) computes one layer's output from
+its in-graph source values, or a graph source's ``.I`` feed, on a map
+from kind to kernel.  The three executors share it: the whole-graph
+reference (``reference_network``, on ``exec.ORACLE_KERNELS``), the
+per-layer Pallas chain (``network_runner`` under ``interpret`` or
+``pallas``, and ``meshexec``'s per-layer tasks, on
+``exec.pallas_kernels``), and the fused trace (``fuse``, on
+``fuse.compiled_kernels``).  What a layer kind feeds and holds comes
+from the kind table (``lower.kinds``).
 
 Layer graphs are analytical specs, so producer/consumer shapes line up
 only approximately (conv halos, flattening before FC, LSTM gate merges,
-inception concat).  A single canonical **adapter** closes the gap, used
-identically by the executor and the whole-graph reference pass
-(``reference_network``) so rel-error comparisons are apples-to-apples:
+inception concat).  The walk closes the gap with one canonical
+**adapter**:
 
   1. equal per-batch size        -> reshape (flatten before FC, 2-D<->4-D);
   2. channel-matched 4-D tensors -> centered zero-pad / crop of the
@@ -35,6 +34,12 @@ an fc with ``meta["last_position"]`` reads each sequence's last token
 (``last_positions``).  A layer with ``meta["tied"]`` reads the weights
 fed for the layer it names (``LayerSpec.weight_owner``), so a looped
 stack's weights are fed once.
+
+**The Pallas chain** realizes the plan's buffer schedule: forwarded
+tensors (segment-internal, see ``netplan``) stay live jax arrays handed
+from producer to consumers; boundary tensors are materialized to host
+numpy after the producer and re-uploaded when consumed, the execution
+analogue of a DRAM store and reload.
 """
 from __future__ import annotations
 
@@ -48,49 +53,20 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels import ref
-from ..kernels.backend import backend_interprets, resolve_backend
+from ..kernels.backend import (DEFAULT_BACKEND, ORACLE_BACKEND,
+                               backend_interprets, resolve_backend)
 from ..obs import metrics, trace, watch
 from ..workloads.layers import LayerSpec
-from .exec import (ORACLE_TOL, _check_compiled_pallas, _run_attention,
-                   _run_conv, _run_eltwise, _run_fc, _run_glu, _run_norm,
-                   _run_pool, attention_reference, input_extent, rel_error)
+from .exec import (ORACLE_KERNELS, ORACLE_TOL, _check_compiled_pallas,
+                   pallas_kernels, rel_error)
+from .kinds import KINDS, heads, required_input_shape
 from .netplan import NetworkPlan
+from .plan import KernelPlan
 
 
 # ---------------------------------------------------------------------------
 # shapes + the canonical adapter
 # ---------------------------------------------------------------------------
-
-
-#: kinds fed a ``<owner>.W`` weight array
-WEIGHT_KINDS = ("conv", "fc", "norm")
-
-
-def _heads(layer: LayerSpec) -> Tuple[int, int]:
-    """(query heads, K/V heads) of an attention layer."""
-    h = int(layer.meta["heads"])
-    return h, int(layer.meta.get("kv_heads", h))
-
-
-def required_input_shape(layer: LayerSpec) -> Tuple[int, ...]:
-    """Canonical input-activation shape each kernel consumes (for an
-    attention layer, its ``qkv`` source's token-major output)."""
-    if layer.kind in ("fc", "norm"):
-        return (layer.dim("N"), layer.dim("C"))
-    if layer.kind == "glu":
-        return (layer.dim("N"), 2 * layer.dim("C"))
-    if layer.kind == "attention":
-        h, kv = _heads(layer)
-        return (int(layer.meta["batch"]) * layer.dim("X"),
-                (h + 2 * kv) * layer.dim("K"))
-    if layer.kind in ("conv", "pool"):
-        XI, YI = input_extent(layer)
-        return (layer.dim("N"), layer.dim("C"), XI, YI)
-    if layer.kind == "eltwise":
-        return (layer.dim("N"), layer.dim("C"), layer.dim("X"),
-                layer.dim("Y"))
-    raise ValueError(f"no network-exec input feed for kind {layer.kind!r}")
 
 
 def adapt_tensor(arr: jnp.ndarray, shape: Tuple[int, ...]) -> jnp.ndarray:
@@ -143,7 +119,7 @@ def split_qkv(layer: LayerSpec, x: jnp.ndarray
     [batch * seq, (heads + 2 * kv_heads) * head_dim] (per token: the
     query heads, then the key heads, then the value heads); K/V heads
     repeat over their query heads."""
-    h, kv = _heads(layer)
+    h, kv = heads(layer)
     b, s, d = int(layer.meta["batch"]), layer.dim("X"), layer.dim("K")
     t = x.reshape(b, s, h + 2 * kv, d).transpose(0, 2, 1, 3)
     q, k, v = t[:, :h], t[:, h:h + kv], t[:, h + kv:]
@@ -156,7 +132,7 @@ def split_qkv(layer: LayerSpec, x: jnp.ndarray
 def merge_heads(layer: LayerSpec, o: jnp.ndarray) -> jnp.ndarray:
     """[batch * heads, seq, head_dim] -> token-major [batch * seq,
     heads * head_dim]."""
-    h, _ = _heads(layer)
+    h, _ = heads(layer)
     b, s, d = int(layer.meta["batch"]), layer.dim("X"), layer.dim("K")
     return o.reshape(b, h, s, d).transpose(0, 2, 1, 3).reshape(b * s,
                                                                 h * d)
@@ -184,6 +160,28 @@ def _eltwise_operands(srcs: Sequence[jnp.ndarray],
     return [adapt_tensor(a, shape) for a in srcs]
 
 
+def layer_output(plan: KernelPlan, srcs: Sequence[jnp.ndarray],
+                 feed: Optional[jnp.ndarray], w: Optional[jnp.ndarray],
+                 kernels: Dict[str, Callable]) -> jnp.ndarray:
+    """The graph walk's one step: a layer's output from its in-graph
+    source values ``srcs`` (or, for a graph source, its ``.I`` ``feed``)
+    and its weight ``w``, on ``kernels[plan.kind]``.  An n-ary sum's
+    sources align through ``_eltwise_operands``.  Any other layer reads
+    its first source through ``layer_input``, or its feed through the
+    adapter; attention splits Q, K and V out of it and merges the heads
+    of its output."""
+    layer = plan.layer
+    kind, kernel = KINDS[plan.kind], kernels[plan.kind]
+    if kind.sums_sources:
+        return kernel(plan, *_eltwise_operands(
+            list(srcs) if srcs else [feed], layer))
+    x = layer_input(layer, srcs[0]) if srcs else \
+        adapt_tensor(feed, required_input_shape(layer))
+    if kind.heads:
+        return merge_heads(layer, kernel(plan, *split_qkv(layer, x)))
+    return kernel(plan, x, w) if kind.weight else kernel(plan, x)
+
+
 # ---------------------------------------------------------------------------
 # deterministic network inputs (external activations + per-layer weights)
 # ---------------------------------------------------------------------------
@@ -196,7 +194,8 @@ def _key(seed: int, name: str) -> jax.Array:
 def make_network_inputs(nplan: NetworkPlan,
                         seed: int = 0) -> Dict[str, jnp.ndarray]:
     """``"<layer>.I"`` external activations for graph sources and
-    ``"<layer>.W"`` weights for conv/fc layers, variance-scaled so
+    ``"<layer>.W"`` weights for the kinds that have them, each from the
+    kind's initialiser (``kinds.LayerKind.weight``): variance-scaled so
     activations stay O(1) through deep graphs, and norm gains near 1.  A
     layer that reads another's weights (``meta["tied"]``) is fed none of
     its own."""
@@ -207,75 +206,51 @@ def make_network_inputs(nplan: NetworkPlan,
             inputs[f"{name}.I"] = jax.random.normal(
                 _key(seed, name + ".I"), required_input_shape(layer),
                 jnp.float32)
-        if layer.weight_owner != name:
-            continue
-        if layer.kind == "norm":
-            inputs[f"{name}.W"] = 1.0 + 0.1 * jax.random.normal(
-                _key(seed, name + ".W"), (layer.dim("C"),), jnp.float32)
-        elif layer.kind == "fc":
-            inputs[f"{name}.W"] = jax.random.normal(
-                _key(seed, name + ".W"),
-                (layer.dim("C"), layer.dim("K")), jnp.float32) \
-                * layer.dim("C") ** -0.5
-        elif layer.kind == "conv":
-            R, S = int(layer.meta["R"]), int(layer.meta["S"])
-            fan_in = layer.dim("C") * R * S
-            inputs[f"{name}.W"] = jax.random.normal(
-                _key(seed, name + ".W"),
-                (layer.dim("K"), layer.dim("C"), R, S), jnp.float32) \
-                * fan_in ** -0.5
+        kind = KINDS.get(layer.kind)    # a kind no tier runs: no weights
+        if kind and kind.weight and layer.weight_owner == name:
+            inputs[f"{name}.W"] = kind.weight(_key(seed, name + ".W"),
+                                              layer)
     return inputs
 
 
 # ---------------------------------------------------------------------------
-# per-layer step functions + the execution chain
+# per-layer steps + the execution chain
 # ---------------------------------------------------------------------------
 
-def _layer_fn(nplan: NetworkPlan, name: str, inputs: Dict,
-              interpret: bool) -> Tuple[Callable, Tuple[str, ...]]:
-    """(fn, src_names): ``fn(*src_arrays) -> output`` for one layer, with
-    the shape adapter folded in (so the whole step jits as one unit)."""
-    plan = nplan.plans[name]
-    layer = plan.layer
-    srcs = tuple(s for s in layer.src if s in nplan.plans)
-    w = inputs.get(f"{layer.weight_owner}.W")
-    ext = inputs.get(f"{name}.I")
-    shape = required_input_shape(layer)
+def _check_executable(nplan: NetworkPlan) -> None:
+    bad = nplan.invalid_layers()
+    if bad:
+        raise ValueError(
+            f"network plan {nplan.graph_name!r} is not executable: "
+            + "; ".join(f"{n}: {r}" for n, r in bad))
 
-    if plan.kind == "fc":
-        def fn(*xs):
-            return _run_fc(plan, layer_input(layer, xs[0] if xs else ext),
-                           w, interpret)
-    elif plan.kind == "norm":
-        def fn(*xs):
-            return _run_norm(plan, layer_input(layer, xs[0] if xs else ext),
-                             w, interpret)
-    elif plan.kind == "glu":
-        def fn(*xs):
-            return _run_glu(plan, layer_input(layer, xs[0] if xs else ext),
-                            interpret)
-    elif plan.kind == "attention":
-        def fn(*xs):
-            q, k, v = split_qkv(layer, layer_input(layer,
-                                                   xs[0] if xs else ext))
-            return merge_heads(layer, _run_attention(plan, q, k, v,
-                                                     interpret))
-    elif plan.kind == "conv":
-        def fn(*xs):
-            return _run_conv(plan, adapt_tensor(xs[0] if xs else ext,
-                                                shape), w, interpret)
-    elif plan.kind == "pool":
-        def fn(*xs):
-            return _run_pool(plan, adapt_tensor(xs[0] if xs else ext,
-                                                shape), interpret)
-    elif plan.kind == "eltwise":
-        def fn(*xs):
-            ops = _eltwise_operands(list(xs) if xs else [ext], layer)
-            return _run_eltwise(plan, ops, interpret)
-    else:
-        raise ValueError(f"cannot execute layer {name!r}: kind "
-                         f"{plan.kind!r} has no network-exec input feed")
-    return fn, srcs
+
+def layer_steps(nplan: NetworkPlan, weights: Dict, backend: str
+                ) -> Dict[str, Tuple[Callable, Tuple[str, ...]]]:
+    """``{layer: (step, args)}``: each layer of the plan as one jitted
+    step of the graph walk on the Pallas kernels of ``backend``
+    (``interpret`` or ``pallas``), with its ``.W`` weight from
+    ``weights`` captured.  ``step(*values)`` takes the values of
+    ``args``: the layer's in-graph sources, or its ``.I`` feed.  Refuses
+    a plan that cannot execute and, under ``pallas``, any plan the
+    compiled Pallas guards (revisit order, TPU tiling) refuse, before
+    anything compiles."""
+    _check_executable(nplan)
+    if backend == "pallas":
+        for name in nplan.order:
+            _check_compiled_pallas(nplan.plans[name])
+    kernels = pallas_kernels(backend_interprets(backend))
+    steps = {}
+    for name in nplan.order:
+        plan = nplan.plans[name]
+        srcs = tuple(s for s in plan.layer.src if s in nplan.plans)
+        w = weights.get(f"{plan.layer.weight_owner}.W")
+
+        def step(*xs, plan=plan, fed=not srcs, w=w):
+            return layer_output(plan, () if fed else xs,
+                                xs[0] if fed else None, w, kernels)
+        steps[name] = (jax.jit(step), srcs or (f"{name}.I",))
+    return steps
 
 
 @dataclasses.dataclass
@@ -293,31 +268,20 @@ class NetworkExecution:
     backend: str = "interpret"
 
 
-def _check_executable(nplan: NetworkPlan) -> None:
-    bad = nplan.invalid_layers()
-    if bad:
-        raise ValueError(
-            f"network plan {nplan.graph_name!r} is not executable: "
-            + "; ".join(f"{n}: {r}" for n, r in bad))
-
-
 def network_runner(nplan: NetworkPlan, inputs: Dict,
-                   interpret: bool = True, jit: bool = True,
-                   backend: Optional[str] = None,
+                   backend: str = ORACLE_BACKEND,
                    keep: str = "all") -> Callable[[], NetworkExecution]:
     """Build a reusable ``() -> NetworkExecution`` for the plan.
 
-    ``backend`` selects the execution tier (``kernels.backend`` is the
-    source of truth; the legacy ``interpret`` bool keeps its meaning when
-    ``backend`` is None):
+    ``backend`` selects the execution tier (``kernels.backend``):
 
       * ``"interpret"`` — per-layer interpret-mode ``pl.pallas_call``
         chain, the bit-accuracy oracle.  Forwarded tensors pass between
         kernels as live jax arrays; boundary tensors are materialized to
         host numpy and re-uploaded at the consumer — the host round-trip
-        that models the DRAM boundary.  With ``jit=True`` each layer step
-        (adapter + kernel) is staged once and re-invocations reuse the
-        compiled executables.
+        that models the DRAM boundary.  Each layer step (adapter +
+        kernel) is staged once and re-invocations reuse the compiled
+        executables (``layer_steps``).
       * ``"pallas"`` — the same chain with compiled Pallas kernels (TPU).
       * ``"compiled"`` — fused segments (``fuse.fused_runner``): the
         whole plan runs as one jitted executable from the process-wide
@@ -326,7 +290,7 @@ def network_runner(nplan: NetworkPlan, inputs: Dict,
         only the graph's sink outputs, ``keep="all"`` every layer output
         (verification).
     """
-    backend = resolve_backend(backend, interpret)
+    backend = resolve_backend(backend)
     if backend == "compiled":
         from .fuse import fused_runner
         fused = fused_runner(nplan)
@@ -345,28 +309,17 @@ def network_runner(nplan: NetworkPlan, inputs: Dict,
                     seconds=time.perf_counter() - t0, backend=backend)
         return run_fused
 
-    _check_executable(nplan)
-    if backend == "pallas":
-        # apply the layer tier's compiled-Pallas guards (revisit order,
-        # TPU tiling) to every plan before anything compiles
-        for name in nplan.order:
-            _check_compiled_pallas(nplan.plans[name])
-    steps = []
-    for name in nplan.order:
-        fn, srcs = _layer_fn(nplan, name, inputs,
-                             backend_interprets(backend))
-        steps.append((name, jax.jit(fn) if jit else fn, srcs,
-                      nplan.placements[name].forwarded))
+    steps = layer_steps(nplan, inputs, backend)
 
     def run() -> NetworkExecution:
         t0 = time.perf_counter()
         onchip: Dict[str, jnp.ndarray] = {}
         host: Dict[str, np.ndarray] = {}
-        for name, fn, srcs, fwd in steps:
-            args = [onchip[s] if s in onchip else jnp.asarray(host[s])
-                    for s in srcs]
-            out = fn(*args)
-            if fwd:
+        for name in nplan.order:
+            fn, args = steps[name]
+            out = fn(*(onchip[s] if s in onchip else jnp.asarray(
+                host[s] if s in host else inputs[s]) for s in args))
+            if nplan.placements[name].forwarded:
                 onchip[name] = out              # stays a live device array
             else:
                 host[name] = np.asarray(out)    # the host round-trip
@@ -382,15 +335,13 @@ def network_runner(nplan: NetworkPlan, inputs: Dict,
 
 
 def execute_network(nplan: NetworkPlan, inputs: Optional[Dict] = None,
-                    interpret: bool = True, seed: int = 0,
-                    jit: bool = True,
-                    backend: Optional[str] = None) -> NetworkExecution:
+                    seed: int = 0,
+                    backend: str = ORACLE_BACKEND) -> NetworkExecution:
     """Run every kernel of the plan in topological order (one-shot
     convenience over ``network_runner``)."""
     inputs = inputs if inputs is not None else make_network_inputs(nplan,
                                                                    seed)
-    return network_runner(nplan, inputs, interpret=interpret, jit=jit,
-                          backend=backend)()
+    return network_runner(nplan, inputs, backend=backend)()
 
 
 # ---------------------------------------------------------------------------
@@ -399,36 +350,16 @@ def execute_network(nplan: NetworkPlan, inputs: Optional[Dict] = None,
 
 def reference_network(nplan: NetworkPlan,
                       inputs: Dict) -> Dict[str, jnp.ndarray]:
-    """Ground truth: the same graph evaluated with the ``kernels/ref.py``
-    oracles and the same canonical adapters, in the same order."""
+    """Ground truth: the graph walk on the ``kernels/ref.py`` oracles
+    (``exec.ORACLE_KERNELS``), in the same order."""
     vals: Dict[str, jnp.ndarray] = {}
     for name in nplan.order:
-        layer = nplan.plans[name].layer
-        srcs = [vals[s] for s in layer.src if s in vals]
-        x = layer_input(layer, srcs[0]) if srcs else inputs[f"{name}.I"]
-        w = inputs.get(f"{layer.weight_owner}.W")
-        if layer.kind == "fc":
-            vals[name] = ref.matmul_ref(x, w)
-        elif layer.kind == "norm":
-            vals[name] = ref.rmsnorm_ref(x, w, float(layer.meta["eps"]))
-        elif layer.kind == "glu":
-            vals[name] = ref.glu_ref(x)
-        elif layer.kind == "attention":
-            vals[name] = merge_heads(
-                layer, attention_reference(layer, *split_qkv(layer, x)))
-        elif layer.kind == "conv":
-            vals[name] = ref.conv2d_ref(x, w,
-                                        stride=int(layer.meta["stride"]))
-        elif layer.kind == "pool":
-            vals[name] = ref.pool2d_ref(x, int(layer.meta["R"]),
-                                        int(layer.meta["S"]),
-                                        stride=int(layer.meta["stride"]))
-        elif layer.kind == "eltwise":
-            ops = _eltwise_operands(srcs if srcs else [inputs[f"{name}.I"]],
-                                    layer)
-            vals[name] = ref.eltwise_ref(*ops)
-        else:
-            raise ValueError(f"no oracle for kind {layer.kind!r}")
+        plan = nplan.plans[name]
+        layer = plan.layer
+        vals[name] = layer_output(
+            plan, [vals[s] for s in layer.src if s in vals],
+            inputs.get(f"{name}.I"), inputs.get(f"{layer.weight_owner}.W"),
+            ORACLE_KERNELS)
     return vals
 
 
@@ -456,16 +387,15 @@ def compare_network(nplan: NetworkPlan, ex: NetworkExecution,
         worst_layer=worst, errors=errors, n_forwarded=len(ex.forwarded))
 
 
-def verify_network(nplan: NetworkPlan, interpret: bool = True,
-                   seed: int = 0, tol: float = ORACLE_TOL, jit: bool = True,
-                   backend: Optional[str] = None) -> NetworkVerification:
+def verify_network(nplan: NetworkPlan, backend: str = ORACLE_BACKEND,
+                   seed: int = 0,
+                   tol: float = ORACLE_TOL) -> NetworkVerification:
     """Execute the plan and compare against the whole-graph reference
     (one-shot convenience over ``compare_network``).  The default backend
     is the interpret oracle; pass ``backend="compiled"`` to verify the
     fused tier (it always keeps every layer output for the comparison)."""
     inputs = make_network_inputs(nplan, seed)
-    ex = execute_network(nplan, inputs, interpret=interpret, jit=jit,
-                         backend=backend)
+    ex = execute_network(nplan, inputs, backend=backend)
     return compare_network(nplan, ex, inputs, tol)
 
 
@@ -500,30 +430,29 @@ def record_latency_drift(predicted_seconds: Optional[float],
 
 
 def measure_network(nplan: NetworkPlan, inputs: Optional[Dict] = None,
-                    interpret: Optional[bool] = None, iters: int = 2,
-                    warmup: int = 1,
+                    iters: int = 2, warmup: int = 1,
                     runner: Optional[Callable[[], NetworkExecution]] = None,
                     predicted_seconds: Optional[float] = None,
                     drift_source: str = "netexec",
-                    backend: Optional[str] = None) -> float:
+                    backend: str = DEFAULT_BACKEND) -> float:
     """Measured wall-clock seconds for one end-to-end network execution
     (min over ``iters`` after ``warmup`` runs compile every layer step).
     Includes the buffer schedule's real host round-trips — network time,
     not a sum of isolated kernel times.  Measurement defaults to the
     **compiled** tier (the serving path: one fused executable per
     segment, boundary outputs only, forwarded tensors never
-    materialize); pass ``backend="interpret"`` (or legacy
-    ``interpret=True``) to time the oracle instead.
+    materialize); pass ``backend="interpret"`` to time the oracle
+    instead.
 
     Pass an existing ``network_runner`` (with ``warmup=0`` if it already
     ran, e.g. for verification) to reuse its compiled steps — the single
     timing protocol behind the calibration sweep and the quickstart."""
-    backend = resolve_backend(backend, interpret)
+    backend = resolve_backend(backend)
     if runner is None:
         inputs = inputs if inputs is not None \
             else make_network_inputs(nplan)
         runner = network_runner(
-            nplan, inputs, jit=True, backend=backend,
+            nplan, inputs, backend=backend,
             keep="boundary" if backend == "compiled" else "all")
         warmup = max(1, warmup)         # fresh steps always need a compile
     for _ in range(warmup):

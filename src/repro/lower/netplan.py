@@ -37,12 +37,6 @@ from ..workloads.layers import LayerGraph
 from ..core.solver.interlayer import _consumer_map
 from .plan import KernelPlan, lower_scheme
 
-#: kinds the network executor can feed from predecessor outputs (an
-#: attention layer splits Q, K and V out of its ``qkv`` source's output)
-NETWORK_EXEC_KINDS = ("conv", "fc", "pool", "eltwise", "attention", "norm",
-                      "glu")
-
-
 @dataclasses.dataclass(frozen=True)
 class SegmentPlan:
     """One chain segment resolved to layer names + node regions."""
@@ -95,10 +89,6 @@ class NetworkPlan:
         """(layer name, reason) for every layer that cannot execute."""
         out = [(n, self.plans[n].invalid_reason) for n in self.order
                if not self.plans[n].valid]
-        out += [(n, f"kind {self.plans[n].kind!r} has no network-exec "
-                 "input feed") for n in self.order
-                if self.plans[n].valid
-                and self.plans[n].kind not in NETWORK_EXEC_KINDS]
         for n in self.order:
             src = self.plans[n].layer.src
             in_graph = sum(1 for s in src if s in self.plans)

@@ -32,9 +32,10 @@ from ..hw.template import HWTemplate
 from ..workloads.layers import DIMS, LayerSpec
 from ..core.cost_model import CostBreakdown, evaluate_layer
 from ..core.directives import LayerScheme, smallest_prime_factor
+from .kinds import KINDS
 
-SUPPORTED_KINDS = ("conv", "fc", "attention", "pool", "eltwise", "norm",
-                   "glu")
+#: the kinds that lower to an executable plan: those of the kind table
+SUPPORTED_KINDS = tuple(KINDS)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -63,7 +63,7 @@ def _run_candidate_impl(rank: int, sched, graph: LayerGraph,
     if bad:
         raise _Skip("; ".join(f"{n}: {r}" for n, r in bad))
     inputs = make_network_inputs(nplan, seed)
-    run = network_runner(nplan, inputs, jit=True, backend=backend)
+    run = network_runner(nplan, inputs, backend=backend)
     ver = compare_network(nplan, run(), inputs, tol)
     if not ver.ok:
         raise _Skip(f"numerics {ver.max_rel_err:.2e} at "
@@ -87,8 +87,7 @@ def _run_candidate_impl(rank: int, sched, graph: LayerGraph,
 
 def autotune_network(graph: LayerGraph, hw: HWTemplate,
                      store: Optional[ScheduleStore] = None, k: int = 3,
-                     iters: int = 2, interpret: Optional[bool] = None,
-                     seed: int = 0,
+                     iters: int = 2, seed: int = 0,
                      max_workers: Optional[int] = None,
                      tol: Optional[float] = None,
                      candidate_timeout_s: Optional[float] = None,
@@ -102,19 +101,20 @@ def autotune_network(graph: LayerGraph, hw: HWTemplate,
     ``candidates`` are the ones that really executed.
 
     Measured re-ranking runs on the fused compiled tier by default
-    (``backend=None`` + ``interpret=None`` resolves to the process
-    default): top-k candidates of the same graph share a plan-signature
-    keyed executable cache, so re-measuring a candidate never re-traces.
-    Pass ``backend="interpret"`` (or legacy ``interpret=True``) to rank
-    on the bit-accuracy oracle instead.  ``tol`` defaults to the stated
-    oracle tolerance (``lower.exec.ORACLE_TOL``).  The report names the
-    backend and the device (``platform``, ``kind``, ``count``) the
-    measurements ran on."""
-    from ..kernels.backend import device_info, resolve_backend
+    (``backend=None`` is ``kernels.backend.DEFAULT_BACKEND``, imported
+    only when a run needs it): top-k candidates of the same graph share
+    a plan-signature keyed executable cache, so re-measuring a candidate
+    never re-traces.  Pass ``backend="interpret"`` to rank on the
+    bit-accuracy oracle instead.  ``tol`` defaults to the stated oracle
+    tolerance (``lower.exec.ORACLE_TOL``).  The report names the backend
+    and the device (``platform``, ``kind``, ``count``) the measurements
+    ran on."""
+    from ..kernels.backend import (DEFAULT_BACKEND, device_info,
+                                   resolve_backend)
     from ..lower.calibrate import spearman
     from ..lower.exec import ORACLE_TOL
 
-    backend = resolve_backend(backend, interpret)
+    backend = resolve_backend(backend or DEFAULT_BACKEND)
     tol = ORACLE_TOL if tol is None else tol
 
     opts = solver_options(**options)

@@ -43,8 +43,7 @@ def _rel_err(a, b) -> float:
 def _oracle(nplan, inputs):
     """Layer-by-layer interpret-mode outputs: the bit-accuracy oracle
     the fused tier is judged against."""
-    return network_runner(nplan, inputs, jit=True,
-                          backend="interpret")().outputs
+    return network_runner(nplan, inputs, backend="interpret")().outputs
 
 
 # ---------------------------------------------------------------------------
@@ -100,7 +99,7 @@ def test_network_runner_compiled_backend():
     nplan = _plan(get_net("mlp", batch=4))
     inputs = make_network_inputs(nplan, seed=0)
     oracle = _oracle(nplan, inputs)
-    ex = network_runner(nplan, inputs, jit=True, backend="compiled")()
+    ex = network_runner(nplan, inputs, backend="compiled")()
     assert ex.backend == "compiled"
     assert set(ex.forwarded) == set(nplan.forwarded())
     for name, val in ex.outputs.items():

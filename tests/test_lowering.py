@@ -15,6 +15,11 @@ from repro.lower import (execute_plan, lower_scheme, lower_schedule,
                          verify_plan)
 from repro.lower.calibrate import (default_hw, run_calibration,
                                    scheme_variants, spearman)
+from repro.lower.exec import ORACLE_KERNELS, pallas_kernels
+from repro.lower.fuse import compiled_kernels
+from repro.lower.kinds import (KINDS, WEIGHT_KINDS, operand_names,
+                               required_input_shape)
+from repro.lower.plan import SUPPORTED_KINDS
 from repro.workloads.layers import (attention, conv, dwconv, eltwise, fc,
                                     glu, pool, rmsnorm)
 from repro.workloads.nets import get_net
@@ -49,16 +54,36 @@ SWEEP = [
 ]
 
 
+@pytest.mark.parametrize("backend", ["interpret", "compiled"])
 @pytest.mark.parametrize("layer", SWEEP, ids=lambda l: l.name)
-def test_lowered_plan_matches_ref(layer):
+def test_lowered_plan_matches_ref(layer, backend):
     plan = lower_scheme(_best_scheme(layer), HW)
     assert plan.valid, plan.reason
     # the grid times the block exactly tiles every dim
     blocked = {ax.dim: ax.steps for ax in plan.grid}
     for d, blk in plan.block.items():
         assert blk * blocked.get(d, 1) == plan.layer.dim(d)
-    ok, err = verify_plan(plan)
+    ok, err = verify_plan(plan, backend=backend)
     assert ok, f"{plan.describe()}: rel err {err:.2e}"
+
+
+def test_every_kind_has_a_kernel_in_every_tier():
+    """A kind in the table lowers, so each tier must run it: a Pallas
+    kernel, a compiled kernel, an oracle, and an input shape for the
+    sweep's layer of that kind."""
+    tiers = {"pallas": pallas_kernels(True), "compiled": compiled_kernels(),
+             "oracle": ORACLE_KERNELS}
+    for name, kernels in tiers.items():
+        assert set(kernels) == set(KINDS), name
+    assert SUPPORTED_KINDS == tuple(KINDS)
+    assert set(WEIGHT_KINDS) == {k for k, rec in KINDS.items()
+                                 if rec.weight is not None}
+    swept = {layer.kind: layer for layer in SWEEP}
+    assert set(swept) == set(KINDS)
+    for kind, layer in swept.items():
+        shape = required_input_shape(layer)
+        assert shape and all(int(n) > 0 for n in shape), kind
+        assert ("W" in operand_names(layer)) == (kind in WEIGHT_KINDS)
 
 
 def test_loop_order_variants_all_match_ref():

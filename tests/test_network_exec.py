@@ -155,11 +155,11 @@ def test_measure_network_and_runner_reuse():
     assert measure_network(nplan, iters=1, warmup=0, runner=run) > 0
 
 
-def test_compiled_mode_applies_revisit_guard():
-    # compiled Pallas cannot accumulate across non-consecutive output-block
-    # revisits; the network runner must enforce the layer tier's guard
+def _reduction_outer_plan():
+    """A one-fc network plan whose DRAM nest puts the reduction axis
+    outermost: interpret mode runs it, compiled Pallas must refuse it."""
     from repro.core.solver.intralayer import Constraints, solve_intra_layer
-    from repro.lower import lower_scheme, network_runner
+    from repro.lower import lower_scheme
     from repro.lower.netplan import NetworkPlan, SegmentPlan, TensorPlacement
     from repro.workloads.layers import fc
     layer = fc("g.fc", 128, 1024, 1024)
@@ -175,10 +175,30 @@ def test_compiled_mode_applies_revisit_guard():
         placements={"g.fc": TensorPlacement("g.fc", (), 0, False,
                                             reason="network output")},
         predicted_latency_cycles=0.0, predicted_energy_pj=0.0)
+    return nplan
+
+
+def test_compiled_mode_applies_revisit_guard():
+    # compiled Pallas cannot accumulate across non-consecutive output-block
+    # revisits; the network runner must enforce the layer tier's guard
+    from repro.lower import network_runner
+    nplan = _reduction_outer_plan()
     inputs = make_network_inputs(nplan)
-    assert network_runner(nplan, inputs, interpret=True) is not None
+    assert network_runner(nplan, inputs, backend="interpret") is not None
     with pytest.raises(ValueError, match="reduction grid axes innermost"):
-        network_runner(nplan, inputs, interpret=False)
+        network_runner(nplan, inputs, backend="pallas")
+
+
+def test_mesh_tasks_apply_revisit_guard():
+    # the mesh executor's per-layer tasks build on the same steps as the
+    # network runner, so compiled Pallas refuses the same plan there
+    from repro.lower.meshexec import build_segment_tasks
+    nplan = _reduction_outer_plan()
+    weights = make_network_inputs(nplan)
+    assert len(build_segment_tasks(nplan, weights,
+                                   backend="interpret")) == 1
+    with pytest.raises(ValueError, match="reduction grid axes innermost"):
+        build_segment_tasks(nplan, weights, backend="pallas")
 
 
 # ---------------------------------------------------------------------------
